@@ -42,6 +42,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     trials = args.trials or (50 if args.quick else 200)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         capacity, comparison, dynamics, engine, hybrid_scaling, kernels,
         maxcut, retrieval, roofline, scaling, serving, sharding,

@@ -21,10 +21,13 @@ N ∈ {506, 1024, 4096}.  506 does not divide 8, so it runs on a 4×2 mesh
 solve is asserted bit-exact against its replicated reference before being
 timed — a wrong fast collective never lands in the JSON.
 
-The bench runs its measurements in a child process with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so the parent
-(benchmarks/run.py, check_regression.py, pytest) keeps its single-device
-jax runtime untouched.
+The bench runs its measurements in a child process pinned to the CPU
+(``JAX_PLATFORMS=cpu``) with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``, so the parent
+(benchmarks/run.py, check_regression.py, pytest) keeps its own jax runtime
+untouched and the child never contends for an accelerator the parent holds.
+Its numbers are a CPU correctness and collective-overhead run, not device
+times.
 
   PYTHONPATH=src python -m benchmarks.sharding                      # full
   PYTHONPATH=src python -m benchmarks.sharding --smoke --out BENCH_sharding.json
@@ -57,7 +60,8 @@ def _child_main(smoke: bool) -> None:
     from repro.distributed import ShardPlan
     from repro.distributed import sharding as shard_lib
 
-    assert jax.device_count() == 8, "child must see the 8-device host mesh"
+    if jax.default_backend() != "cpu" or jax.device_count() != 8:
+        raise RuntimeError("sharding child must see the 8-device CPU host mesh")
     max_cycles = 4 if smoke else 16
     trials = 3 if smoke else 7
     lanes = 4 if smoke else 8
@@ -128,6 +132,7 @@ def main(
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(repo_root, "src"), env.get("PYTHONPATH")) if p
     )
@@ -143,7 +148,8 @@ def main(
     child = json.loads(proc.stdout.strip().splitlines()[-1])
     rows = child["rows"]
 
-    print("# model-parallel sharding vs replicated (8 virtual host devices)")
+    print("# model-parallel sharding vs replicated: CPU correctness/overhead "
+          "run on 8 virtual host devices (not device times)")
     print("n,mesh,replicated_s,sharded_s,per_device_weight_mb,"
           "full_weight_mb,memory_headroom_x")
     for r in rows:
@@ -169,6 +175,7 @@ def main(
         payload = {
             "bench": "sharding",
             "smoke": smoke,
+            "platform": "cpu",
             "devices": 8,
             "calibration_s": child["calibration_s"],
             "rows": rows,

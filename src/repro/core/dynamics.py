@@ -477,7 +477,6 @@ def _model_sharded_sum(
     real work.  ``plan.compressed`` swaps the exact int32 combine for the
     int8 wire format :func:`repro.optim.compress.compressed_psum_scatter`.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = w.shape[0]
@@ -503,12 +502,12 @@ def _model_sharded_sum(
         lead = "data"
     sigma_spec = P(*([lead] + [None] * (sigma.ndim - 1)))
     out_spec = P(*([lead] + [None] * (sigma.ndim - 1)))
-    out = shard_map(
+    out = jax.shard_map(
         local_block,
         mesh=mesh,
         in_specs=(P("model", None), sigma_spec),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )(w, sigma)
     return out[..., :m] if m_pad != m else out
 

@@ -75,6 +75,13 @@ class MaxCutResult(NamedTuple):
     sweeps_run: Optional[jax.Array] = None  # (...,) sweeps executed (early exit)
 
 
+#: Cut values are float32 contractions of integer-valued spins and weights.
+#: A TPU's default matmul precision rounds float32 operands to bfloat16,
+#: which is inexact past 256 (an intermediate row sum at N=506), so the cut
+#: contractions ask for full float32 precision.
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 def maxcut_couplings(adjacency: jax.Array, weight_bits: int = 5):
     """Quantized ONN couplings for max-cut: J = −A (antiferromagnetic)."""
     return quantize_weights(-adjacency.astype(jnp.float32), bits=weight_bits)
@@ -84,7 +91,7 @@ def cut_value_exact(adjacency: jax.Array, sigma: jax.Array) -> jax.Array:
     """Weighted cut size Σ_{i<j} A_ij (1 − σ_i σ_j) / 2; ``sigma``: (..., N)."""
     sig = sigma.astype(jnp.float32)
     a = jnp.triu(adjacency.astype(jnp.float32), k=1)
-    pair = jnp.einsum("...i,ij,...j->...", sig, a, sig)
+    pair = jnp.einsum("...i,ij,...j->...", sig, a, sig, precision=_EXACT)
     total = jnp.sum(a)
     return 0.5 * (total - pair)
 
@@ -209,7 +216,7 @@ def _solve_single(
 
     def cuts_of(sig: jax.Array) -> jax.Array:  # (R, N) -> (R,)
         s = sig.astype(jnp.float32)
-        return 0.5 * (total_w - jnp.einsum("ri,ij,rj->r", s, a_tri, s))
+        return 0.5 * (total_w - jnp.einsum("ri,ij,rj->r", s, a_tri, s, precision=_EXACT))
 
     k_init, k_anneal = jax.random.split(key)
     u0 = _replica_index_uniform(k_init, replicas, n)

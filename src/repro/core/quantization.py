@@ -99,43 +99,6 @@ def unpack_int4(packed: jax.Array) -> jax.Array:
     return out.reshape(*packed.shape[:-1], packed.shape[-1] * 2)
 
 
-def pack_phases(phases: jax.Array) -> jax.Array:
-    """Pack 4-bit phase counters two per byte (low nibble first).
-
-    ``phases`` holds *unsigned* counters in [0, 16) — the rotating-frame
-    phase state of a ``phase_bits <= 4`` ONN — so no sign handling is
-    needed (contrast :func:`pack_int4`).  An odd last axis is padded with a
-    zero nibble; :func:`unpack_phases` takes the true length to slice it
-    back off.  Returns ``uint8`` of last-axis length ``ceil(n / 2)``.
-    """
-    n = phases.shape[-1]
-    if n % 2 != 0:
-        widths = [(0, 0)] * (phases.ndim - 1) + [(0, 1)]
-        phases = jnp.pad(phases, widths)
-    lo = phases[..., 0::2].astype(jnp.uint32) & 0xF
-    hi = phases[..., 1::2].astype(jnp.uint32) & 0xF
-    return (lo | (hi << 4)).astype(jnp.uint8)
-
-
-def unpack_phases(packed: jax.Array, n: int) -> jax.Array:
-    """Inverse of :func:`pack_phases`: ``(..., ceil(n/2))`` → ``(..., n)`` uint8.
-
-    Nibbles are unsigned phase counters — no sign extension (contrast
-    :func:`unpack_int4`).  ``n`` is the true last-axis length; the zero pad
-    nibble of an odd ``n`` is sliced off.
-    """
-    if packed.shape[-1] != (n + 1) // 2:
-        raise ValueError(
-            f"unpack_phases: packed last axis {packed.shape[-1]} != "
-            f"ceil({n}/2) = {(n + 1) // 2}"
-        )
-    lo = (packed.astype(jnp.uint32) & 0xF).astype(jnp.uint8)
-    hi = ((packed.astype(jnp.uint32) >> 4) & 0xF).astype(jnp.uint8)
-    out = jnp.stack([lo, hi], axis=-1)
-    out = out.reshape(*packed.shape[:-1], packed.shape[-1] * 2)
-    return out[..., :n]
-
-
 def weight_memory_bits(n: int, bits: int = DEFAULT_WEIGHT_BITS) -> int:
     """Total coupling-weight memory in bits for an N-oscillator ONN (Table 1)."""
     return n * n * bits

@@ -43,7 +43,7 @@ import re
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 _LAYOUTS = ("row", "replicated")
 
@@ -113,8 +113,14 @@ class ShardPlan:
         return cls(batch=data, model=model)
 
     def make_mesh(self) -> Mesh:
-        """A local ``(batch, model)`` mesh with axes ``("data", "model")``."""
-        return jax.make_mesh((self.batch, self.model), ("data", "model"))
+        """A local ``(batch, model)`` mesh with axes ``("data", "model")``.
+
+        Axes are ``Auto``: the solve places its lanes and weight rows with
+        sharding constraints and ``shard_map``, which explicit axes reject.
+        """
+        return jax.make_mesh(
+            (self.batch, self.model), ("data", "model"), (AxisType.Auto,) * 2
+        )
 
     @contextlib.contextmanager
     def context(self, mesh: Optional[Mesh] = None):

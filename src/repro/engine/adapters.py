@@ -352,13 +352,9 @@ class RetrievalEngineSolver:
         path uses it to stay off the device's critical path)."""
         remaining: List[jax.Array] = []
         for arr in self._settle_pending:
-            if not block:
-                try:
-                    if not arr.is_ready():
-                        remaining.append(arr)
-                        continue
-                except AttributeError:  # jax without Array.is_ready()
-                    pass
+            if not block and not arr.is_ready():
+                remaining.append(arr)
+                continue
             mean_eff = float(arr)
             a = self.SETTLE_EMA_ALPHA
             self._settle_ema = (

@@ -36,14 +36,16 @@ from repro.kernels import coupling_kernel as _k
 #: output block.
 VMEM_BUDGET_BYTES = (16 * 2**20) // 4
 
-#: Budget for the multi-cycle kernel, whose (N, N) weight tile stays
-#: *resident* across the whole launch — no second weight tile is ever in
-#: flight, so it may use half of VMEM rather than a quarter.
-MULTI_VMEM_BUDGET_BYTES = (16 * 2**20) // 2
+#: Budget for the multi-cycle kernel's pipelined blocks
+#: (:func:`repro.kernels.coupling_kernel.multi_vmem_bytes`): the 16 MiB
+#: scoped-VMEM limit of a v5e kernel, less 2 MiB for the values the
+#: in-kernel cycle loop keeps live.  The kernel runs one grid step per
+#: batch block, so its blocks may fill the limit instead of a quarter of it.
+MULTI_VMEM_BUDGET_BYTES = 14 * 2**20
 
 #: Largest padded N whose resident (N, N) int8 weight tile fits the
-#: multi-cycle kernel's budget (N² bytes = 4 MiB at N = 2048, leaving the
-#: other 4 MiB for phase/bookkeeping blocks).  Single source of truth —
+#: multi-cycle kernel's budget (two N² buffers = 8 MiB at N = 2048, leaving
+#: the rest for phase/bookkeeping blocks).  Single source of truth —
 #: ``repro.core.dynamics._multi_kernel_eligible`` gates on it.
 MULTI_KERNEL_MAX_N = 2048
 
@@ -78,6 +80,17 @@ def _pick(size: int, preferred: int, minimum: int = 8) -> int:
     while b > minimum and b > size:
         b //= 2
     return max(b, minimum)
+
+
+def _lane_tile(size: int, preferred: int, minimum: int = 8) -> int:
+    """A tile for an axis that lands on the 128-wide lane dimension of some
+    block: a multiple of 128, or — for extents below 128 — the whole extent
+    rounded up to a multiple of 8.  A TPU block's last dimension must span
+    whole lane groups or the whole (padded) operand: a 32-wide tile of a
+    64-wide operand does not lower, and neither does a one-row MAC tile."""
+    if size < 128:
+        return -(-size // minimum) * minimum
+    return _pick(size, preferred, minimum)
 
 
 def _shrink_to_budget(bb: int, bi: int, bk: int, minimum: int = 8) -> BlockConfig:
@@ -127,7 +140,7 @@ def blocks_for(kind: str, *, n: int, batch: int, m: int | None = None) -> BlockC
         # f32 GEMV: long contraction blocks amortize the weight stream; the
         # batch extent is decode-sized.
         bb = _pick(batch, 8)
-        bm = _pick(m, _k.DEFAULT_BLOCK_I)
+        bm = _lane_tile(m, _k.DEFAULT_BLOCK_I)
         bk = _pick(n, 512, minimum=128)
         cfg = _shrink_to_budget(bb, bm, bk, minimum=8)
     else:
@@ -135,8 +148,8 @@ def blocks_for(kind: str, *, n: int, batch: int, m: int | None = None) -> BlockC
         # and row tiles pay off once the operand extent supports them (fewer
         # grid steps over the same bytes); small buckets shrink toward their
         # extent as before.
-        bi = _pick(m, 256 if m >= 256 else 128)
-        bk = _pick(n, 256 if n >= 256 else 128)
+        bi = _lane_tile(m, 256 if m >= 256 else 128)
+        bk = _lane_tile(n, 256 if n >= 256 else 128)
         cfg = _shrink_to_budget(bb, bi, bk)
     _CACHE[key] = cfg
     return cfg

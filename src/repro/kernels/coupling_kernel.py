@@ -14,9 +14,10 @@ Kernels
 * ``onn_step_fused``: σ'[b,i] = sign-align(S[b,i] + h[i])    (fused epilogue)
 * ``quantized_matvec``: y = (W_q · scale) @ x                 (int8 × f32 GEMV)
 
-All are validated against ``ref.py`` in interpret mode (this container is
-CPU-only); block shapes are hardware-aligned for the 128×128 MXU and the
-(32, 128) int8 VMEM tile.
+All are validated against ``ref.py`` in interpret mode on the CPU and
+compiled for a described TPU v5e by ``tests/test_tpu_compile.py``; block
+shapes are hardware-aligned for the 128×128 MXU and the (32, 128) int8 VMEM
+tile.
 """
 
 from __future__ import annotations
@@ -292,18 +293,49 @@ def phase_step_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _unpack_nibbles(packed: jax.Array, width: int) -> jax.Array:
-    """(bb, width/2) packed uint8 → (bb, width) int32 counters (low first)."""
+# Kernel transport layout ("block halves"): within every ``width``-wide
+# column block, byte j holds counter j in its low nibble and counter
+# j + width/2 in its high nibble.  Unpacking is then two lane-aligned
+# bit-field extracts and one concatenation — Mosaic has no lowering for the
+# (w/2, 2) → w interleave that a layout of adjacent pairs would need.  The
+# packed kernels
+# therefore take blocks whose half-width is a multiple of the 128-lane tile
+# (:data:`PACKED_BLOCK_MULTIPLE`); the XLA-side converters below map between
+# this layout and plain (B, N) counters.
+
+#: Column-block multiple of the packed kernels: half a block must fill whole
+#: 128-lane vregs.
+PACKED_BLOCK_MULTIPLE = 256
+
+
+def pack_block_halves(vals: jax.Array, width: int) -> jax.Array:
+    """(B, N) counters in [0, 16) → (B, N/2) uint8 in the block-halves layout."""
+    b, n = vals.shape
+    _require(n % width == 0 and width % 2 == 0,
+             f"pack_block_halves: N={n} not a multiple of even width={width}")
+    v = vals.astype(jnp.uint8).reshape(b, n // width, 2, width // 2)
+    return (v[:, :, 0] | (v[:, :, 1] << 4)).reshape(b, n // 2)
+
+
+def unpack_block_halves(packed: jax.Array, width: int) -> jax.Array:
+    """Inverse of :func:`pack_block_halves`: (B, N/2) uint8 → (B, N) uint8."""
+    b, n_half = packed.shape
+    _require((2 * n_half) % width == 0,
+             f"unpack_block_halves: N={2 * n_half} not a multiple of width={width}")
+    p = packed.reshape(b, (2 * n_half) // width, 1, width // 2)
+    return jnp.concatenate([p & 0xF, p >> 4], axis=2).reshape(b, 2 * n_half)
+
+
+def _unpack_nibbles(packed: jax.Array) -> jax.Array:
+    """(bb, w/2) block-halves uint8 → (bb, w) int32 counters (in-kernel)."""
     p = packed.astype(jnp.int32)
-    lo = p & 0xF
-    hi = (p >> 4) & 0xF
-    return jnp.stack([lo, hi], axis=-1).reshape(packed.shape[0], width)
+    return jnp.concatenate([p & 0xF, (p >> 4) & 0xF], axis=-1)
 
 
 def _pack_nibbles(vals: jax.Array) -> jax.Array:
-    """(bb, width) int32 counters in [0, 16) → (bb, width/2) uint8."""
-    v = vals.reshape(vals.shape[0], vals.shape[1] // 2, 2)
-    return (v[..., 0] | (v[..., 1] << 4)).astype(jnp.uint8)
+    """(bb, w) int32 counters in [0, 16) → (bb, w/2) block-halves uint8."""
+    h = vals.shape[1] // 2
+    return (vals[:, :h] | (vals[:, h:] << 4)).astype(jnp.uint8)
 
 
 def packed_phase_vmem_bytes(bb: int, bi: int, bk: int) -> int:
@@ -327,7 +359,7 @@ def _phase_step_packed_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # σ derived in-register from the packed θ tile: σ = +1 iff θ < half.
-    theta = _unpack_nibbles(packed_sig_ref[...], w_ref.shape[1])
+    theta = _unpack_nibbles(packed_sig_ref[...])
     sigma = jnp.where(theta < half, 1, -1).astype(jnp.int8)
     acc_ref[...] += jax.lax.dot_general(
         sigma,
@@ -339,7 +371,7 @@ def _phase_step_packed_kernel(
     @pl.when(k == nk - 1)
     def _epilogue():
         s = acc_ref[...] + bias_ref[...].astype(jnp.int32)  # (bb, bi)
-        keep = _unpack_nibbles(packed_keep_ref[...], acc_ref.shape[1])
+        keep = _unpack_nibbles(packed_keep_ref[...])
         out_ref[...] = jnp.where(
             s > 0, jnp.int32(0), jnp.where(s < 0, jnp.int32(half), keep)
         )
@@ -358,12 +390,15 @@ def phase_step_packed_pallas(
 ) -> jax.Array:
     """Packed-operand θ' = phase-align(W σ(θ) + h, θ), one launch per cycle.
 
-    ``packed_phase``: (B, N/2) uint8, two 4-bit phase counters per byte
-    (:func:`repro.core.quantization.pack_phases`); both the σ operand of the
-    MAC tile and the epilogue's keep-θ view unpack it in-register, so it is
-    the *only* per-lane array the kernel reads.  Returns (B, N) int32 phases
-    (``phase_step_pallas`` contract).  Padded θ entries must be 0 (σ = +1
-    against zero weight columns — inert, the ``pad_sigma`` convention).
+    ``packed_phase``: (B, N/2) uint8, two 4-bit phase counters per byte in
+    the block-halves layout at width ``block_k``
+    (:func:`pack_block_halves`); both the σ operand of the MAC tile and the
+    epilogue's keep-θ view unpack it in-register, so it is the *only*
+    per-lane array the kernel reads — which is why ``block_i`` must equal
+    ``block_k`` (a multiple of :data:`PACKED_BLOCK_MULTIPLE`).  Returns
+    (B, N) int32 phases (``phase_step_pallas`` contract).  Padded θ entries
+    must be 0 (σ = +1 against zero weight columns — inert, the
+    ``pad_sigma`` convention).
     """
     b, n_half = packed_phase.shape
     ni, nk = w.shape
@@ -374,8 +409,9 @@ def phase_step_packed_pallas(
     )
     _require(bias.shape == (ni,), f"phase_step_packed_pallas: bias {bias.shape} != ({ni},)")
     _require(
-        block_i % 2 == 0 and block_k % 2 == 0,
-        f"phase_step_packed_pallas: blocks ({block_i}, {block_k}) must be even",
+        block_i == block_k and block_k % PACKED_BLOCK_MULTIPLE == 0,
+        f"phase_step_packed_pallas: blocks ({block_i}, {block_k}) must be equal "
+        f"multiples of {PACKED_BLOCK_MULTIPLE}",
     )
     _require(
         b % block_b == 0 and ni % block_i == 0 and nk % block_k == 0,
@@ -412,13 +448,20 @@ def phase_step_packed_pallas(
 
 
 def multi_vmem_bytes(block_b: int, n: int, packed: bool = False) -> int:
-    """VMEM working set of one ``phase_step_multi_pallas`` grid step."""
-    w = n * n  # int8, resident for all `chunk` cycles
-    phase = block_b * (n // 2 if packed else n * 4) * 2  # θ and prev-θ
-    bias = n * 4
-    flags = block_b * 1 * 4 * 7  # seven (bb, 1) int32 bookkeeping columns
-    live = block_b * n * (4 + 1)  # int32 field + int8 σ of the live cycle
-    return w + phase + bias + flags + live
+    """Scoped VMEM of the pipelined blocks of one ``phase_step_multi_pallas``
+    grid step.
+
+    The pipeline keeps two buffers of every block — the resident W too,
+    although its block index never changes — and the phase state is both
+    read and written (θ and prev-θ, in and out).  The v5e compiler's scoped
+    allocation at N=2048/B=128 unpacked was 16.68 MiB, this estimate plus
+    0.65 MiB of in-kernel values.
+    """
+    w = 2 * n * n  # int8, resident for all `chunk` cycles
+    phase = 2 * 4 * block_b * (n // 2 if packed else n * 4)  # θ, prev-θ in and out
+    bias = 2 * n * 4
+    flags = 2 * 14 * block_b * 4  # seven (bb, 1) int32 columns in and out
+    return w + phase + bias + flags
 
 
 def _phase_step_multi_kernel(
@@ -451,8 +494,8 @@ def _phase_step_multi_kernel(
     w = w_ref[...]
     bias = bias_ref[...].astype(jnp.int32)  # (1, n)
     if packed:
-        ph0 = _unpack_nibbles(phase_ref[...], n)
-        prev0 = _unpack_nibbles(prev_ref[...], n)
+        ph0 = _unpack_nibbles(phase_ref[...])
+        prev0 = _unpack_nibbles(prev_ref[...])
     else:
         ph0 = phase_ref[...]
         prev0 = prev_ref[...]
@@ -541,8 +584,9 @@ def phase_step_multi_pallas(
     launch; grid is 1-D over the batch (the weight matrix stays resident).
 
     ``phase``/``prev_phase``: (B, N) int32 counters — or (B, N/2) packed
-    uint8 when ``packed`` (two counters per byte, unpacked in-register every
-    cycle and re-packed in the epilogue).  The seven bookkeeping columns are
+    uint8 when ``packed`` (two counters per byte in the block-halves layout
+    at width N, :func:`pack_block_halves`; unpacked in-register at launch
+    and re-packed in the epilogue).  The seven bookkeeping columns are
     (B, 1) int32 (bools as {0, 1}).  Returns the 9-tuple
     (phase, prev_phase, settle_cycle, settled, cycled, frozen, frozen_p2,
     freeze_cycle, t) with the same shapes/dtypes as the inputs.
@@ -553,7 +597,11 @@ def phase_step_multi_pallas(
     ph_cols = nk // 2 if packed else nk
     ph_dtype = jnp.uint8 if packed else jnp.int32
     if packed:
-        _require(nk % 2 == 0, f"phase_step_multi_pallas: packed N={nk} must be even")
+        _require(
+            nk % PACKED_BLOCK_MULTIPLE == 0,
+            f"phase_step_multi_pallas: packed N={nk} must be a multiple of "
+            f"{PACKED_BLOCK_MULTIPLE}",
+        )
     for name, arr in (("phase", phase), ("prev_phase", prev_phase)):
         _require(
             arr.shape == (b, ph_cols),

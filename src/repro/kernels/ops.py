@@ -1,9 +1,10 @@
 """Public wrappers around the Pallas kernels.
 
-Handles padding to hardware-aligned block multiples, batch reshaping, backend
-selection (interpret mode on CPU — this container — and compiled mode on
-TPU), and a pure-jnp fallback (``use_pallas=False``) used by the large CPU
-benchmark sweeps where interpret-mode execution would dominate runtime.
+Handles padding to hardware-aligned block multiples, batch reshaping, and
+execution mode: the kernels compile on a TPU and run in interpret mode only
+when JAX's default backend is the CPU (the test suite).  ``use_pallas=False``
+takes the pure-jnp reference route instead; nothing on the solver path
+passes it, so only a caller that asks for it gets it.
 
 Block resolution happens *here*, in plain Python, before the jitted inner
 implementation is entered: explicit ``block_*`` arguments are honored (and
@@ -34,6 +35,7 @@ TRACE_COUNTER: collections.Counter = collections.Counter()
 
 
 def _interpret() -> bool:
+    """Interpret the kernels iff JAX's default backend is the CPU."""
     return jax.default_backend() == "cpu"
 
 
@@ -215,8 +217,6 @@ def _phase_step_packed_jit(w, bias, phase, *, half, use_pallas, block_b, block_i
     TRACE_COUNTER["phase_step_packed"] += 1
     _require_int_dtype(w, "w")
     _require_int_dtype(bias, "bias")
-    from repro.core.quantization import pack_phases  # local: avoid import cycle
-
     squeeze = phase.ndim == 1
     batch_shape = phase.shape[:-1]
     n = w.shape[0]
@@ -225,18 +225,19 @@ def _phase_step_packed_jit(w, bias, phase, *, half, use_pallas, block_b, block_i
     if not use_pallas:
         out = _ref.phase_step_packed_ref(w, h, ph2d, half)
     else:
-        # The packed array feeds both the σ-derivation tile (block_k columns)
-        # and the epilogue's keep-θ tile (block_i columns), so N pads to a
-        # common (even) multiple and W stays square at the padded size.
-        n_mult = max(block_i, block_k)
-        n_pad = -(-n // n_mult) * n_mult
+        # The packed array feeds both the σ-derivation tile and the
+        # epilogue's keep-θ tile, so one square column block serves both
+        # (block-halves layout at that width) and W stays square at the
+        # padded size.
+        blk = -(-max(block_i, block_k) // _k.PACKED_BLOCK_MULTIPLE) * _k.PACKED_BLOCK_MULTIPLE
+        n_pad = -(-n // blk) * blk
         ph_p = _k.pad_to_blocks(ph2d, (block_b, 0))
         ph_p = jnp.pad(ph_p, ((0, 0), (0, n_pad - n)))
         w_p = jnp.pad(w.astype(jnp.int8), ((0, n_pad - n), (0, n_pad - n)))
         h_p = jnp.pad(h, (0, n_pad - n))
         out = _k.phase_step_packed_pallas(
-            pack_phases(ph_p), w_p, h_p,
-            half=half, block_b=block_b, block_i=block_i, block_k=block_k,
+            _k.pack_block_halves(ph_p, blk), w_p, h_p,
+            half=half, block_b=block_b, block_i=blk, block_k=blk,
             interpret=_interpret(),
         )[: ph2d.shape[0], :n]
     out = out.astype(phase.dtype)
@@ -285,8 +286,6 @@ def _phase_step_multi_jit(
     TRACE_COUNTER["phase_step_multi"] += 1
     _require_int_dtype(w, "w")
     _require_int_dtype(bias, "bias")
-    from repro.core.quantization import pack_phases, unpack_phases  # avoid cycle
-
     b, n = phase.shape
     h = jnp.zeros((n,), jnp.int32) if bias is None else bias.astype(jnp.int32)
     cols = (t, settle_cycle, settled, cycled, frozen, frozen_p2, freeze_cycle)
@@ -299,19 +298,21 @@ def _phase_step_multi_jit(
         ph_o, prev_o = outs[0], outs[1]
         flag_o = outs[2:]
     else:
-        # N pads to an (even) lane multiple: padded oscillators carry θ = 0
-        # against zero weight rows/columns, so they never change and never
-        # perturb the all-lanes reductions.  Batch pads with born-frozen
-        # lanes (t = max_cycles), inert under the active mask.
-        n_pad = -(-n // 128) * 128
+        # N pads to a lane multiple (256 for the packed layout): padded
+        # oscillators carry θ = 0 against zero weight rows/columns, so they
+        # never change and never perturb the all-lanes reductions.  Batch
+        # pads with born-frozen lanes (t = max_cycles), inert under the
+        # active mask.
+        n_mult = _k.PACKED_BLOCK_MULTIPLE if packed else 128
+        n_pad = -(-n // n_mult) * n_mult
         b_pad = -(-b // block_b) * block_b
         w_p = jnp.pad(w.astype(jnp.int8), ((0, n_pad - n), (0, n_pad - n)))
         h_p = jnp.pad(h, (0, n_pad - n))
         ph_p = jnp.pad(phase.astype(jnp.int32), ((0, b_pad - b), (0, n_pad - n)))
         prev_p = jnp.pad(prev_phase.astype(jnp.int32), ((0, b_pad - b), (0, n_pad - n)))
         if packed:
-            ph_p = pack_phases(ph_p.astype(jnp.uint8))
-            prev_p = pack_phases(prev_p.astype(jnp.uint8))
+            ph_p = _k.pack_block_halves(ph_p, n_pad)
+            prev_p = _k.pack_block_halves(prev_p, n_pad)
         pad_dead = ((0, b_pad - b), (0, 0))
         t_p = jnp.pad(cols32[0], pad_dead, constant_values=max_cycles)
         fz_p = jnp.pad(cols32[4], pad_dead, constant_values=1)
@@ -324,8 +325,8 @@ def _phase_step_multi_jit(
         )
         ph_o, prev_o = outs[0][:b], outs[1][:b]
         if packed:
-            ph_o = unpack_phases(ph_o, n_pad).astype(jnp.int32)
-            prev_o = unpack_phases(prev_o, n_pad).astype(jnp.int32)
+            ph_o = _k.unpack_block_halves(ph_o, n_pad).astype(jnp.int32)
+            prev_o = _k.unpack_block_halves(prev_o, n_pad).astype(jnp.int32)
         ph_o, prev_o = ph_o[:, :n], prev_o[:, :n]
         flag_o = tuple(o[:b] for o in outs[2:])
     sc_o, sd_o, cy_o, fz_o, fp2_o, fc_o, t_o = flag_o

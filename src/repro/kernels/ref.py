@@ -97,7 +97,7 @@ def phase_step_packed_ref(
 
     ``phase``: (B, N) *unpacked* int counters (the packing is a transport
     layout, not a semantic change); σ = +1 iff θ < half.  Matches
-    ``phase_step_packed_pallas`` fed ``pack_phases(phase)``.
+    ``phase_step_packed_pallas`` fed ``pack_block_halves(phase, width)``.
     """
     sigma = jnp.where(phase.astype(jnp.int32) < half, 1, -1).astype(jnp.int8)
     return phase_step_ref(w, sigma, bias, phase, half)

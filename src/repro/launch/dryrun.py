@@ -1,7 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run driver (deliverable e).
 
 Lowers + compiles every (architecture × input shape) cell — plus the two ONN
@@ -14,9 +10,10 @@ cells — against the production mesh, WITHOUT allocating any real arrays
 
 into ``artifacts/dryrun/<arch>__<shape>__<mesh>[__<tag>].json``.
 
-NOTE the XLA_FLAGS line above MUST precede every other import (jax locks the
-device count at first init) — and must NOT leak into conftest.py or
-pyproject: smoke tests and benches see 1 device, this driver sees 512.
+``main`` forces 512 placeholder host devices through ``XLA_FLAGS`` before
+anything initializes a JAX backend (the device count is fixed at first
+init).  Importing this module changes nothing: smoke tests and benches keep
+seeing 1 device.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-4b --shape train_4k
@@ -28,6 +25,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import time
 from typing import Any, Dict, Optional
 
@@ -646,7 +644,12 @@ def run_onn_cell(
 # ---------------------------------------------------------------------------
 
 
+#: Placeholder host devices the dry-run meshes are built from.
+DRYRUN_XLA_FLAGS = "--xla_force_host_platform_device_count=512"
+
+
 def main() -> None:
+    os.environ["XLA_FLAGS"] = DRYRUN_XLA_FLAGS
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", type=str, default=None)
     ap.add_argument("--shape", type=str, default=None)

@@ -41,6 +41,7 @@ from repro.api import MaxCutSolver
 from repro.core.ising import random_graph
 from repro.distributed import ShardPlan
 from repro.engine import DEFAULT_BATCH_BUCKETS, Engine, Request
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.retrieve import _plan_of_mesh_kwarg, resolve_plan_args
 
 
@@ -121,6 +122,7 @@ def serve_cuts(
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--n", type=int, default=64, help="vertices per instance")
     ap.add_argument("--requests", type=int, default=16)

@@ -44,6 +44,7 @@ from repro.data import patterns as pat
 from repro.distributed import ShardPlan, plan_of_legacy_shard_batch
 from repro.distributed import sharding as shard_lib
 from repro.engine import DEFAULT_BATCH_BUCKETS, Engine, Request
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_solver(
@@ -218,6 +219,7 @@ def serve_requests(
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--dataset", default="10x10", choices=list(pat.DATASET_SHAPES))
     ap.add_argument("--architecture", default="hybrid", choices=["hybrid", "recurrent"])

@@ -34,6 +34,7 @@ import jax
 
 from repro import serving
 from repro.distributed import ShardPlan
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def parse_weights(spec: str) -> Tuple[Tuple[str, float], ...]:
@@ -98,6 +99,7 @@ def run_daemon(
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--rate", type=float, default=20.0, help="arrival rate (req/s)")
     ap.add_argument("--requests", type=int, default=100)

@@ -30,7 +30,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def quantize(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -147,7 +146,7 @@ def compressed_grads(
 
     outs_g, outs_e = [], []
     for g, e, spec in zip(flat_g, flat_e, specs):
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(compressed_psum_mean, axis_name=axis_name),
             mesh=mesh,
             in_specs=(spec, spec),

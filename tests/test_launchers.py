@@ -1,5 +1,11 @@
 """End-to-end launcher tests: train loop (checkpoint/restart), serving loop,
-ONN retrieval service, engine-served max-cut."""
+ONN retrieval service, engine-served max-cut, and the process and
+compile-cache rules of the entry points."""
+
+import os
+import shutil
+import subprocess
+import sys
 
 import pytest
 
@@ -113,3 +119,78 @@ def test_train_onn_hot_swap_flow(tmp_path):
     assert out["serving_retraces_after_swap"] == 0
     assert out["checkpoint"] is not None
     assert out["completed"] == 3 * out["probes"]  # warmup + two phases
+
+
+# ---------------------------------------------------------------------------
+# Process and compile-cache rules of the entry points
+# ---------------------------------------------------------------------------
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run_python(args, cwd, **env):
+    full_env = {**os.environ, "PYTHONPATH": os.path.join(REPO_ROOT, "src"), **env}
+    return subprocess.run(
+        [sys.executable, *args], cwd=cwd, env=full_env, capture_output=True,
+        text=True, timeout=300,
+    )
+
+
+def test_chip_smoke_refuses_the_cpu():
+    """No silent CPU fallback: without a TPU the smoke run exits non-zero
+    and never prints its ok line."""
+    out = _run_python([os.path.join(REPO_ROOT, "chip_smoke.py")], REPO_ROOT, JAX_PLATFORMS="cpu")
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+    assert "needs a TPU" in out.stderr
+
+
+def test_chip_smoke_alone_fails(tmp_path):
+    """Copied away from the checkout, the script cannot reach the program."""
+    shutil.copy(os.path.join(REPO_ROOT, "chip_smoke.py"), tmp_path)
+    out = _run_python(["chip_smoke.py"], str(tmp_path), JAX_PLATFORMS="cpu", PYTHONPATH="")
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+
+
+def test_compile_cache_keeps_a_preset_directory(monkeypatch, tmp_path):
+    import jax
+
+    from repro.launch import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.compile_cache_dir() is None
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_one_checkout_path(monkeypatch):
+    import jax
+
+    from repro.launch import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first = compile_cache.compile_cache_dir()
+    assert first == compile_cache.compile_cache_dir()
+    assert os.path.dirname(first) == REPO_ROOT
+    with open(os.path.join(REPO_ROOT, ".gitignore")) as f:
+        assert os.path.basename(first) + "/" in f.read().split()
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert compile_cache.enable_compile_cache() == first
+        assert jax.config.jax_compilation_cache_dir == first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_importing_dryrun_leaves_xla_flags_alone():
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO_ROOT, "src"), JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import os, repro.launch.dryrun; print(os.environ.get('XLA_FLAGS'))"],
+        env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == "None"

@@ -253,16 +253,15 @@ def test_streaming_midflight_join_on_sharded_slab():
 _COMPRESSED_SCRIPT = _PRELUDE + textwrap.dedent(
     """
     import functools
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.optim import compress
 
     # 1) error-feedback round-trip of the gradient collective on the 8-way
     # mesh: the EF telescoping identity — summed over shards AND steps, the
     # decoded means (x n_dev) plus the final residuals reconstruct the raw
     # gradients (quantization error never accumulates, it only carries).
-    mesh = jax.make_mesh((8,), ("data",))
-    fn = jax.jit(shard_map(
+    mesh = jax.make_mesh((8,), ("data",), (AxisType.Auto,))
+    fn = jax.jit(jax.shard_map(
         functools.partial(compress.compressed_psum_mean, axis_name="data"),
         mesh=mesh, in_specs=(P("data"), P("data")),
         out_specs=(P("data"), P("data")),

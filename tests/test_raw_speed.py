@@ -15,8 +15,8 @@ except ModuleNotFoundError:  # optional dep (see pyproject.toml): skip, not fail
 
 from repro import engine as engine_lib
 from repro.core import dynamics
-from repro.core.quantization import pack_phases, unpack_phases
 from repro.kernels import autotune, ops, ref
+from repro.kernels.coupling_kernel import pack_block_halves, unpack_block_halves
 from repro.serving import ContinuousEngine
 
 RESULT_FIELDS = ("final_phase", "final_sigma", "settle_cycle", "settled", "cycled")
@@ -31,33 +31,37 @@ def _instance(n: int, batch: int, seed: int = 0):
 
 
 # ---------------------------------------------------------------------------
-# pack_phases / unpack_phases
+# pack_block_halves / unpack_block_halves (the packed kernels' layout)
 # ---------------------------------------------------------------------------
 
 
 @given(
-    st.integers(1, 40),
+    st.sampled_from([2, 8, 256]),
+    st.integers(1, 3),
     st.integers(1, 5),
     st.integers(0, 2**31 - 1),
 )
 @settings(max_examples=30, deadline=None)
-def test_pack_unpack_roundtrip(n, b, seed):
+def test_pack_unpack_roundtrip(width, blocks, b, seed):
     rng = np.random.default_rng(seed)
-    phases = jnp.asarray(rng.integers(0, 16, (b, n)), jnp.uint8)
-    packed = pack_phases(phases)
+    phases = jnp.asarray(rng.integers(0, 16, (b, width * blocks)), jnp.uint8)
+    packed = pack_block_halves(phases, width)
     assert packed.dtype == jnp.uint8
-    assert packed.shape == (b, (n + 1) // 2)
-    back = unpack_phases(packed, n)
+    assert packed.shape == (b, width * blocks // 2)
+    back = unpack_block_halves(packed, width)
     np.testing.assert_array_equal(np.asarray(back), np.asarray(phases))
 
 
 def test_pack_unpack_edge_shapes():
-    one = jnp.asarray([5], jnp.uint8)  # odd singleton: hi nibble is padding
-    packed = pack_phases(one)
-    assert packed.shape == (1,) and int(packed[0]) == 5
-    np.testing.assert_array_equal(np.asarray(unpack_phases(packed, 1)), [5])
+    # Byte j of each w-wide block holds counter j (low) and j + w/2 (high).
+    vals = jnp.asarray([[1, 2, 3, 4, 5, 6, 7, 8]], jnp.uint8)
+    packed = pack_block_halves(vals, 4)
+    np.testing.assert_array_equal(np.asarray(packed), [[0x31, 0x42, 0x75, 0x86]])
+    np.testing.assert_array_equal(np.asarray(unpack_block_halves(packed, 4)), vals)
     with pytest.raises(ValueError):
-        unpack_phases(jnp.zeros((2, 3), jnp.uint8), 9)  # needs ceil(9/2)=5
+        pack_block_halves(jnp.zeros((2, 6), jnp.uint8), 4)  # 6 is not a multiple of 4
+    with pytest.raises(ValueError):
+        unpack_block_halves(jnp.zeros((2, 3), jnp.uint8), 4)
 
 
 def test_phase_pack_requires_4bit_phases():

@@ -80,7 +80,7 @@ _SUBPROCESS_TEST = textwrap.dedent(
     import json
     import jax
     import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
     from repro import configs
     from repro.distributed import sharding as shrules
@@ -89,7 +89,7 @@ _SUBPROCESS_TEST = textwrap.dedent(
     from repro.models.config import ShapeConfig
 
     assert jax.device_count() == 8
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"), (AxisType.Auto,) * 2)
     rules = shrules.single_pod_rules()
     cfg = configs.get_reduced("qwen3-4b")
     shape = ShapeConfig("tiny_train", 64, 8, "train")

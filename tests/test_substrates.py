@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro import checkpoint as ckpt
 from repro import optim
@@ -119,7 +120,7 @@ def test_checkpoint_elastic_restore_new_mesh(tmp_path):
     d = str(tmp_path)
     tree = _tree()
     ckpt.save(d, 3, tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), (AxisType.Auto,))
     sh = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("data"))
     target = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
     shardings = jax.tree.map(lambda x: sh if x.shape else None, target)
@@ -250,14 +251,13 @@ def test_error_feedback_unbiased_over_steps():
 
 
 def test_compressed_psum_mean_single_device():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), (AxisType.Auto,))
     x = jnp.arange(8, dtype=jnp.float32) / 10
     e = jnp.zeros((8,))
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     import functools
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(compress.compressed_psum_mean, axis_name="data"),
         mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
     )

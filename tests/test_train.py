@@ -130,7 +130,8 @@ def test_self_coupling_off_masks_stability_check():
         np.asarray(jnp.diagonal(res.weights)), np.zeros(24, np.float32)
     )
     masked = learning.stability_margins(res.weights * (1.0 - jnp.eye(24)), xi)
-    assert float(jnp.min(masked)) >= 1.0
+    # Recomputed outside the trainer, so float32 rounding may differ.
+    assert float(jnp.min(masked)) >= 1.0 - 1e-6
 
 
 def test_train_config_validation():
