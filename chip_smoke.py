@@ -106,14 +106,9 @@ def has_kernel(fn, *args) -> bool:
     return "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
 
 
-def engine_bucket(n: int) -> int:
-    """The N bucket the engine's default (pow2) policy pads an instance to."""
-    return bucketing.bucket_n(n, "pow2")
-
-
 def engine_instance(solver):
     """(config, params) of a retrieval solver padded to its engine bucket."""
-    n_b = engine_bucket(solver.config.n)
+    n_b = bucketing.bucket_n(solver.config.n)
     return (
         dynamics.pad_config(solver.config, n_b),
         dynamics.pad_params(solver.config, solver.params, n_b),
@@ -258,7 +253,7 @@ def phase_maxcut():
     ratio = np.asarray(res.cut_value) / half_edges
     check(bool((ratio > 1.0).all()), f"max-cut: cut not above |E|/2 ({ratio})")
     # The engine pads N to its pow2 bucket; compile that slab's solve.
-    n_b = engine_bucket(MAXCUT_N)
+    n_b = bucketing.bucket_n(MAXCUT_N)
     cfg_b = solver.config(n_b)
     adjs = np.zeros((MAXCUT_REQUESTS, n_b, n_b), np.int8)
     kernel = has_kernel(

@@ -14,7 +14,6 @@ importing this module changes nothing.
 from __future__ import annotations
 
 import os
-from typing import Optional
 
 #: The in-checkout cache directory used when the environment names none.
 CHECKOUT_CACHE_DIR = os.path.abspath(
@@ -22,20 +21,16 @@ CHECKOUT_CACHE_DIR = os.path.abspath(
 )
 
 
-def compile_cache_dir() -> Optional[str]:
-    """The directory :func:`enable_compile_cache` would set, or ``None`` when
-    ``JAX_COMPILATION_CACHE_DIR`` is set and JAX already uses it."""
-    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        return None
-    return CHECKOUT_CACHE_DIR
-
-
 def enable_compile_cache() -> str:
-    """Turn the persistent compilation cache on; returns its directory."""
+    """Turn the persistent compilation cache on; returns its directory.
+
+    A set ``JAX_COMPILATION_CACHE_DIR`` is left to JAX, which reads it
+    itself; otherwise the cache goes to :data:`CHECKOUT_CACHE_DIR`.
+    """
+    preset = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if preset:
+        return preset
     import jax
 
-    path = compile_cache_dir()
-    if path is None:
-        return os.environ["JAX_COMPILATION_CACHE_DIR"]
-    jax.config.update("jax_compilation_cache_dir", path)
-    return path
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    return CHECKOUT_CACHE_DIR

@@ -160,7 +160,6 @@ def test_compile_cache_keeps_a_preset_directory(monkeypatch, tmp_path):
 
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     before = jax.config.jax_compilation_cache_dir
-    assert compile_cache.compile_cache_dir() is None
     assert compile_cache.enable_compile_cache() == str(tmp_path)
     assert jax.config.jax_compilation_cache_dir == before
 
@@ -171,15 +170,14 @@ def test_compile_cache_defaults_to_one_checkout_path(monkeypatch):
     from repro.launch import compile_cache
 
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    first = compile_cache.compile_cache_dir()
-    assert first == compile_cache.compile_cache_dir()
-    assert os.path.dirname(first) == REPO_ROOT
-    with open(os.path.join(REPO_ROOT, ".gitignore")) as f:
-        assert os.path.basename(first) + "/" in f.read().split()
     before = jax.config.jax_compilation_cache_dir
     try:
+        first = compile_cache.enable_compile_cache()
         assert compile_cache.enable_compile_cache() == first
         assert jax.config.jax_compilation_cache_dir == first
+        assert os.path.dirname(first) == REPO_ROOT
+        with open(os.path.join(REPO_ROOT, ".gitignore")) as f:
+            assert os.path.basename(first) + "/" in f.read().split()
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
 

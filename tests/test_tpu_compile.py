@@ -44,7 +44,7 @@ def _multi(n, b, packed):
     blocks = autotune.blocks_for("multi", n=n, batch=b)
     mult = k.PACKED_BLOCK_MULTIPLE if packed else 128
     n_pad = -(-n // mult) * mult
-    ph =((b, n_pad // 2), jnp.uint8) if packed else ((b, n_pad), jnp.int32)
+    ph = ((b, n_pad // 2), jnp.uint8) if packed else ((b, n_pad), jnp.int32)
     flag = ((b, 1), jnp.int32)
     fn = functools.partial(
         k.phase_step_multi_pallas, half=8, chunk=8, max_cycles=100, packed=packed,
