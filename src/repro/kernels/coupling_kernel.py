@@ -298,10 +298,9 @@ def phase_step_pallas(
 # j + width/2 in its high nibble.  Unpacking is then two lane-aligned
 # bit-field extracts and one concatenation — Mosaic has no lowering for the
 # (w/2, 2) → w interleave that a layout of adjacent pairs would need.  The
-# packed kernels
-# therefore take blocks whose half-width is a multiple of the 128-lane tile
-# (:data:`PACKED_BLOCK_MULTIPLE`); the XLA-side converters below map between
-# this layout and plain (B, N) counters.
+# packed kernels therefore take blocks whose half-width is a multiple of the
+# 128-lane tile (:data:`PACKED_BLOCK_MULTIPLE`); the XLA-side converters
+# below map between this layout and plain (B, N) counters.
 
 #: Column-block multiple of the packed kernels: half a block must fill whole
 #: 128-lane vregs.
