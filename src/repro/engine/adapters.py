@@ -21,7 +21,7 @@ import numpy as np
 from repro.core import dynamics
 from repro.core import hardware_model as hw
 from repro.core import ising as ising_lib
-from repro.engine import bucketing
+from repro.engine import bucketing, spans
 from repro.engine.registry import register_solver
 from repro.kernels import autotune
 
@@ -200,45 +200,48 @@ class RetrievalEngineSolver:
     ) -> List[Any]:
         from repro import api  # local: api imports this module
 
-        autotune.warm(n=bucket_sig, batch=batch_bucket)
-        cfg_b, params_b = self._padded_instance(bucket_sig)
-        lanes2d = [jnp.atleast_2d(jnp.asarray(p, jnp.int8)) for p in payloads]
-        counts = [x.shape[0] for x in lanes2d]
-        batch = dynamics.pad_sigma(jnp.concatenate(lanes2d, axis=0), bucket_sig)
-        total = batch.shape[0]
-        if total < batch_bucket:
-            pad_rows = jnp.ones((batch_bucket - total, bucket_sig), jnp.int8)
-            batch = jnp.concatenate([batch, pad_rows], axis=0)
+        with spans.span(spans.PACK):
+            autotune.warm(n=bucket_sig, batch=batch_bucket)
+            cfg_b, params_b = self._padded_instance(bucket_sig)
+            lanes2d = [jnp.atleast_2d(jnp.asarray(p, jnp.int8)) for p in payloads]
+            counts = [x.shape[0] for x in lanes2d]
+            batch = dynamics.pad_sigma(jnp.concatenate(lanes2d, axis=0), bucket_sig)
+            total = batch.shape[0]
+            if total < batch_bucket:
+                pad_rows = jnp.ones((batch_bucket - total, bucket_sig), jnp.int8)
+                batch = jnp.concatenate([batch, pad_rows], axis=0)
 
-        lane_keys = None
-        if self._draws_randomness():
-            per_lane: List[jax.Array] = []
-            for k, c in zip(keys, counts):
-                per_lane.extend(jax.random.split(k, c))
-            lane_keys = _stack_keys(per_lane, batch_bucket)
+            lane_keys = None
+            if self._draws_randomness():
+                per_lane: List[jax.Array] = []
+                for k, c in zip(keys, counts):
+                    per_lane.extend(jax.random.split(k, c))
+                lane_keys = _stack_keys(per_lane, batch_bucket)
 
-        res = api.retrieve(cfg_b, params_b, batch, lane_keys)
-        self._observe_settle(res, total)
-        n = self.config.n
-        out: List[Any] = []
-        offset = 0
-        for p, c in zip(payloads, counts):
-            # Gather by an index *operand* rather than a static slice: the
-            # executable is keyed by the lane count only, not by where the
-            # request landed in the slab (a static [offset:offset+c] start
-            # compiles one slicer per offset — unbounded under live load).
-            idx = jnp.arange(offset, offset + c, dtype=jnp.int32)
-            r = dynamics.ONNResult(
-                final_phase=res.final_phase[idx, :n],
-                final_sigma=res.final_sigma[idx, :n],
-                settle_cycle=res.settle_cycle[idx],
-                settled=res.settled[idx],
-                cycled=res.cycled[idx],
-            )
-            if jnp.asarray(p).ndim == 1:  # single-lane payload → unbatched result
-                r = jax.tree.map(lambda x: x[0], r)
-            out.append(r)
-            offset += c
+        with spans.span(spans.SOLVE):
+            res = api.retrieve(cfg_b, params_b, batch, lane_keys)
+        with spans.span(spans.SPLIT):
+            self._observe_settle(res, total)
+            n = self.config.n
+            out: List[Any] = []
+            offset = 0
+            for p, c in zip(payloads, counts):
+                # Gather by an index *operand* rather than a static slice: the
+                # executable is keyed by the lane count only, not by where the
+                # request landed in the slab (a static [offset:offset+c] start
+                # compiles one slicer per offset — unbounded under live load).
+                idx = jnp.arange(offset, offset + c, dtype=jnp.int32)
+                r = dynamics.ONNResult(
+                    final_phase=res.final_phase[idx, :n],
+                    final_sigma=res.final_sigma[idx, :n],
+                    settle_cycle=res.settle_cycle[idx],
+                    settled=res.settled[idx],
+                    cycled=res.cycled[idx],
+                )
+                if jnp.asarray(p).ndim == 1:  # single-lane payload → unbatched result
+                    r = jax.tree.map(lambda x: x[0], r)
+                out.append(r)
+                offset += c
         return out
 
     # -- streaming slab protocol (continuous batching: repro.serving) -------
@@ -293,7 +296,9 @@ class RetrievalEngineSolver:
 
     def done_mask(self, slab: RetrievalSlab) -> Any:
         """(width,) host bool array: lanes whose results are final."""
-        return jax.device_get(dynamics.batch_done(slab.cfg, slab.state))
+        done = dynamics.batch_done(slab.cfg, slab.state)
+        with spans.span(spans.SYNC):
+            return jax.device_get(done)
 
     def results(self, slab: RetrievalSlab) -> dynamics.ONNResult:
         """Slab-wide results on the host (call once per harvest tick, then
@@ -305,7 +310,9 @@ class RetrievalEngineSolver:
         against the slab's sharded device arrays (those compile per
         (shape, sharding) and would leak XLA compiles into steady-state
         serving)."""
-        return jax.device_get(dynamics.batch_result(slab.cfg, slab.state))
+        res = dynamics.batch_result(slab.cfg, slab.state)
+        with spans.span(spans.SYNC):
+            return jax.device_get(res)
 
     def extract(
         self, res: dynamics.ONNResult, slots: Sequence[int], payload: Any
@@ -355,7 +362,8 @@ class RetrievalEngineSolver:
             if not block and not arr.is_ready():
                 remaining.append(arr)
                 continue
-            mean_eff = float(arr)
+            with spans.span(spans.SYNC):
+                mean_eff = float(arr)
             a = self.SETTLE_EMA_ALPHA
             self._settle_ema = (
                 mean_eff
@@ -532,38 +540,46 @@ class MaxCutEngineSolver:
         # Ising's staggered sweep contracts (group, N) row slabs through the
         # same weighted_sum kernels; warm the tuner on the replica-expanded
         # batch so the first solve at this bucket resolves blocks cache-hot.
-        autotune.warm(n=nb, batch=max(1, batch_bucket * self.replicas), kinds=("step", "hybrid"))
-        cfg = self._bucket_config(nb)
-        padded, true_n = [], []
-        for p in payloads:
-            a = jnp.asarray(p)
-            pad = nb - a.shape[0]
-            padded.append(jnp.pad(a, ((0, pad), (0, pad))))
-            true_n.append(a.shape[0])
-        while len(padded) < batch_bucket:  # dead rows: zero graph, no vertices
-            padded.append(jnp.zeros((nb, nb), padded[0].dtype))
-            true_n.append(0)
-        res = ising_lib.solve_maxcut_batch(
-            cfg,
-            jnp.stack(padded),
-            _stack_keys(list(keys), batch_bucket),
-            replicas=self.replicas,
-            stagger_groups=self.stagger_groups,
-            stagnation=self.stagnation,
-            true_n=jnp.asarray(true_n, jnp.int32),
-        )
-        out = []
-        for i, p in enumerate(payloads):
-            n = jnp.asarray(p).shape[0]
-            out.append(
-                ising_lib.MaxCutResult(
-                    sigma=res.sigma[i, :n],
-                    cut_value=res.cut_value[i],
-                    trace=res.trace[i],
-                    replica_cuts=res.replica_cuts[i],
-                    sweeps_run=res.sweeps_run[i],
-                )
+        with spans.span(spans.PACK):
+            autotune.warm(
+                n=nb, batch=max(1, batch_bucket * self.replicas), kinds=("step", "hybrid")
             )
+            cfg = self._bucket_config(nb)
+            padded, true_n = [], []
+            for p in payloads:
+                a = jnp.asarray(p)
+                pad = nb - a.shape[0]
+                padded.append(jnp.pad(a, ((0, pad), (0, pad))))
+                true_n.append(a.shape[0])
+            while len(padded) < batch_bucket:  # dead rows: zero graph, no vertices
+                padded.append(jnp.zeros((nb, nb), padded[0].dtype))
+                true_n.append(0)
+            graphs = jnp.stack(padded)
+            lane_keys = _stack_keys(list(keys), batch_bucket)
+            true_n = jnp.asarray(true_n, jnp.int32)
+        with spans.span(spans.SOLVE):
+            res = ising_lib.solve_maxcut_batch(
+                cfg,
+                graphs,
+                lane_keys,
+                replicas=self.replicas,
+                stagger_groups=self.stagger_groups,
+                stagnation=self.stagnation,
+                true_n=true_n,
+            )
+        with spans.span(spans.SPLIT):
+            out = []
+            for i, p in enumerate(payloads):
+                n = jnp.asarray(p).shape[0]
+                out.append(
+                    ising_lib.MaxCutResult(
+                        sigma=res.sigma[i, :n],
+                        cut_value=res.cut_value[i],
+                        trace=res.trace[i],
+                        replica_cuts=res.replica_cuts[i],
+                        sweeps_run=res.sweeps_run[i],
+                    )
+                )
         return out
 
     def stats(self) -> Dict[str, Any]:

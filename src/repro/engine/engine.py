@@ -26,7 +26,7 @@ see ``repro.core.dynamics.pad_params`` for the bit-exactness argument.
 from __future__ import annotations
 
 import dataclasses
-import time
+import itertools
 from concurrent.futures import Future
 from typing import Any, Dict, Hashable, List, Optional, Protocol, Tuple, runtime_checkable
 
@@ -34,6 +34,7 @@ import jax
 
 from repro.engine import bucketing
 from repro.engine import registry as registry_lib
+from repro.engine import spans
 from repro.engine.planner import Estimate, Planner
 
 
@@ -118,6 +119,7 @@ class _Pending:
     lanes: int
     key: jax.Array
     estimate: Estimate
+    seq: int  # the request's number, its spans' ``request`` attribute
 
 
 class Engine:
@@ -177,6 +179,8 @@ class Engine:
             "lanes_served": 0,
             "lanes_padding": 0,
         }
+        self._seq = itertools.count()  # request numbers
+        self._slab_seq = itertools.count()  # slab numbers
         self._tenants: Dict[str, Dict[str, int]] = {}
         self._bucket_log: Dict[Tuple[str, Hashable, int], int] = {}
 
@@ -242,32 +246,29 @@ class Engine:
         return sum(p.lanes for ps in self._queues.values() for p in ps)
 
     def _make_pending(
-        self, request: Request
+        self, request: Request, seq: int
     ) -> Tuple[_Pending, Tuple[str, Hashable], int]:
         """Validate + bucket + quote + key-split one request (not enqueued)."""
         solver = self.solver(request.workload)
-        lanes = solver.lane_count(request.payload)
-        if lanes > self.batch_buckets[-1]:
-            raise ValueError(
-                f"request has {lanes} lanes > largest batch bucket "
-                f"{self.batch_buckets[-1]}; split it or widen batch_buckets"
-            )
-        sig = solver.signature(request.payload)
-        bucket_sig = solver.bucket(sig, self.n_policy)
+        with spans.span(spans.VALIDATE, request=seq):
+            lanes = solver.lane_count(request.payload)
+            if lanes > self.batch_buckets[-1]:
+                raise ValueError(
+                    f"request has {lanes} lanes > largest batch bucket "
+                    f"{self.batch_buckets[-1]}; split it or widen batch_buckets"
+                )
+            sig = solver.signature(request.payload)
+            bucket_sig = solver.bucket(sig, self.n_policy)
         qkey = (request.workload, bucket_sig)
-        bb = bucketing.bucket_batch(lanes, self.batch_buckets)
-        est = self.planner.estimate(
-            (request.workload, bucket_sig, bb),
-            units=solver.cost_units(bucket_sig, bb),
-            fpga_seconds=solver.fpga_seconds(bucket_sig),
-            fpga_tradeoff=self._fpga_tradeoff(solver, bucket_sig),
-        )
+        with spans.span(spans.QUOTE, request=seq):
+            est = self._quote(request.workload, solver, bucket_sig, lanes)
         pending = _Pending(
             request=request,
             future=Future(),
             lanes=lanes,
             key=request.key if request.key is not None else self._next_key(),
             estimate=est,
+            seq=seq,
         )
         return pending, qkey, lanes
 
@@ -291,11 +292,13 @@ class Engine:
         latency estimate (readable via :meth:`stats` while pending).  Raises
         :class:`QueueFullError` when admission control rejects it.
         """
-        pending, qkey, lanes = self._make_pending(request)
-        self._admit(request, lanes)
-        self._queues.setdefault(qkey, []).append(pending)
-        self._counts["submitted"] += 1
-        self._tenant_counters(request.tenant)["submitted"] += 1
+        seq = next(self._seq)
+        with spans.span(spans.SUBMIT, request=seq):
+            pending, qkey, lanes = self._make_pending(request, seq)
+            self._admit(request, lanes)
+            self._queues.setdefault(qkey, []).append(pending)
+            self._counts["submitted"] += 1
+            self._tenant_counters(request.tenant)["submitted"] += 1
         if self.auto_flush:
             if sum(p.lanes for p in self._queues[qkey]) >= self.batch_buckets[-1]:
                 self._flush_queue(qkey)
@@ -327,15 +330,17 @@ class Engine:
         solver = self._solvers[workload]
         lanes = sum(p.lanes for p in slab)
         bb = bucketing.bucket_batch(lanes, self.batch_buckets)
-        t0 = time.perf_counter()
-        try:
-            results = solver.solve_bucket(
-                bucket_sig, [p.request.payload for p in slab], [p.key for p in slab], bb
-            )
-        except Exception as exc:  # noqa: BLE001 — propagate through futures
-            self._fail_slab(slab, exc)
-            return
-        seconds = time.perf_counter() - t0
+        with spans.span(
+            spans.SLAB, slab=next(self._slab_seq), workload=workload, bucket=bucket_sig,
+            requests=len(slab), lanes=lanes, width=bb, ids=[p.seq for p in slab],
+        ) as slab_span:
+            try:
+                results = solver.solve_bucket(
+                    bucket_sig, [p.request.payload for p in slab], [p.key for p in slab], bb
+                )
+            except Exception as exc:  # noqa: BLE001 — propagate through futures
+                self._fail_slab(slab, exc)
+                return
         if len(results) != len(slab):
             self._fail_slab(
                 slab,
@@ -347,7 +352,7 @@ class Engine:
             return
         self.planner.observe(
             (workload, bucket_sig, bb),
-            seconds,
+            slab_span.seconds,
             units=solver.cost_units(bucket_sig, bb),
         )
         for p, r in zip(slab, results):
@@ -386,8 +391,10 @@ class Engine:
 
     def drain(self) -> Dict[str, Any]:
         """Serve everything pending; returns :meth:`stats` afterwards."""
-        self.flush()
-        return self.stats()
+        with spans.span(spans.DRAIN):
+            self.flush()
+            with spans.span(spans.STATS):
+                return self.stats()
 
     # -- introspection -----------------------------------------------------
 
@@ -397,17 +404,22 @@ class Engine:
         tradeoff = getattr(solver, "fpga_tradeoff", None)
         return tradeoff(bucket_sig) if callable(tradeoff) else None
 
-    def estimate(self, workload: str, payload: Any) -> Estimate:
-        """Latency quote for a hypothetical request (nothing enqueued)."""
-        solver = self.solver(workload)
-        bucket_sig = solver.bucket(solver.signature(payload), self.n_policy)
-        bb = bucketing.bucket_batch(solver.lane_count(payload), self.batch_buckets)
+    def _quote(
+        self, workload: str, solver: EngineSolver, bucket_sig: Hashable, lanes: int
+    ) -> Estimate:
+        bb = bucketing.bucket_batch(lanes, self.batch_buckets)
         return self.planner.estimate(
             (workload, bucket_sig, bb),
             units=solver.cost_units(bucket_sig, bb),
             fpga_seconds=solver.fpga_seconds(bucket_sig),
             fpga_tradeoff=self._fpga_tradeoff(solver, bucket_sig),
         )
+
+    def estimate(self, workload: str, payload: Any) -> Estimate:
+        """Latency quote for a hypothetical request (nothing enqueued)."""
+        solver = self.solver(workload)
+        bucket_sig = solver.bucket(solver.signature(payload), self.n_policy)
+        return self._quote(workload, solver, bucket_sig, solver.lane_count(payload))
 
     def stats(self) -> Dict[str, Any]:
         served = self._counts["lanes_served"]

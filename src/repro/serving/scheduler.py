@@ -19,12 +19,11 @@ queues (:class:`repro.serving.admission.FairQueues`).
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, Hashable, List, Optional, Tuple
 
 import jax
 
-from repro.engine import bucketing
+from repro.engine import bucketing, spans
 from repro.engine.engine import Engine, Request, _Pending
 from repro.kernels import autotune
 from repro.serving.admission import FairQueues
@@ -98,11 +97,13 @@ class ContinuousEngine(Engine):
 
     def submit(self, request: Request) -> Any:
         """Enqueue into the fair queues; served by :meth:`step` ticks."""
-        pending, qkey, lanes = self._make_pending(request)
-        self._admit(request, lanes)
-        self._fair.push(request.tenant, qkey, pending, lanes)
-        self._counts["submitted"] += 1
-        self._tenant_counters(request.tenant)["submitted"] += 1
+        seq = next(self._seq)
+        with spans.span(spans.SUBMIT, request=seq):
+            pending, qkey, lanes = self._make_pending(request, seq)
+            self._admit(request, lanes)
+            self._fair.push(request.tenant, qkey, pending, lanes)
+            self._counts["submitted"] += 1
+            self._tenant_counters(request.tenant)["submitted"] += 1
         return pending.future
 
     def _queued_lanes(self) -> int:
@@ -192,7 +193,8 @@ class ContinuousEngine(Engine):
 
         ``admit=False`` freezes admission (drain mode): live slabs keep
         advancing but freed slots are not refilled.  Returns a report with
-        per-slab advance seconds for latency anomaly detection.
+        per-slab advance-and-harvest seconds (the ``serving.advance`` and
+        ``serving.harvest`` spans) for latency anomaly detection.
         """
         self._serving_counts["ticks"] += 1
         report: Dict[str, Any] = {
@@ -202,42 +204,48 @@ class ContinuousEngine(Engine):
             "blocking_served": 0,
             "slab_seconds": {},
         }
-        if admit:
-            for qkey in self._fair.qkeys():
+        with spans.span(spans.TICK, tick=self._serving_counts["ticks"]):
+            if admit:
+                with spans.span(spans.BACKFILL):
+                    self._admit_queued(report)
+            for qkey, rec in list(self._slabs.items()):
                 workload, bucket_sig = qkey
-                if self._is_streaming(workload):
-                    rec = self._ensure_slab(qkey)
-                    if rec is not None:
-                        a, j = self._backfill(qkey, rec)
-                        report["admitted"] += a
-                        report["mid_flight_joins"] += j
-                        self._serving_counts["mid_flight_joins"] += j
-                else:
-                    # Blocking workloads run whole slabs inside one tick.
-                    popped = self._fair.pop_all(qkey)
-                    pendings = [p for _, p, _ in popped]
-                    for slab in self._pack(pendings):
-                        self._run_slab(workload, bucket_sig, slab)
-                    report["blocking_served"] += len(pendings)
-
-        for qkey, rec in list(self._slabs.items()):
-            workload, bucket_sig = qkey
-            solver = self._solvers[workload]
-            if rec.entries:
-                t0 = time.perf_counter()
-                solver.advance(rec.slab)
-                harvested = self._harvest(qkey, rec)  # syncs on done_mask
-                dt = time.perf_counter() - t0
-                rec.advanced = True
-                self._serving_counts["chunks"] += 1
-                report["harvested"] += harvested
-                report["slab_seconds"][f"{workload}:{bucket_sig!r}"] = dt
-            if not rec.entries and (
-                rec.pending_resize or self._fair.queued_lanes(qkey) == 0
-            ):
-                del self._slabs[qkey]
-                self._serving_counts["slabs_retired"] += 1
+                solver = self._solvers[workload]
+                if rec.entries:
+                    label = f"{workload}:{bucket_sig!r}"
+                    with spans.span(spans.ADVANCE, slab=label, requests=len(rec.entries)) as adv:
+                        solver.advance(rec.slab)
+                    with spans.span(spans.HARVEST, slab=label) as harvest:
+                        harvested = self._harvest(qkey, rec)  # syncs on done_mask
+                    rec.advanced = True
+                    self._serving_counts["chunks"] += 1
+                    report["harvested"] += harvested
+                    report["slab_seconds"][label] = adv.seconds + harvest.seconds
+                if not rec.entries and (
+                    rec.pending_resize or self._fair.queued_lanes(qkey) == 0
+                ):
+                    del self._slabs[qkey]
+                    self._serving_counts["slabs_retired"] += 1
         return report
+
+    def _admit_queued(self, report: Dict[str, Any]) -> None:
+        """Backfill streaming slabs from the fair queues; blocking workloads
+        run their whole slabs here."""
+        for qkey in self._fair.qkeys():
+            workload, bucket_sig = qkey
+            if self._is_streaming(workload):
+                rec = self._ensure_slab(qkey)
+                if rec is not None:
+                    a, j = self._backfill(qkey, rec)
+                    report["admitted"] += a
+                    report["mid_flight_joins"] += j
+                    self._serving_counts["mid_flight_joins"] += j
+            else:
+                popped = self._fair.pop_all(qkey)
+                pendings = [p for _, p, _ in popped]
+                for slab in self._pack(pendings):
+                    self._run_slab(workload, bucket_sig, slab)
+                report["blocking_served"] += len(pendings)
 
     # -- hot weight install ------------------------------------------------
 
