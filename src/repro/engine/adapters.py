@@ -130,7 +130,6 @@ class RetrievalEngineSolver:
         self._padded: Dict[int, Tuple[Any, Any]] = {}
         self._settle_ema: Optional[float] = None
         self._settle_obs: int = 0
-        self._settle_pending: List[jax.Array] = []  # per-slab mean, on device
         self._swaps: int = 0
 
     @property
@@ -138,12 +137,10 @@ class RetrievalEngineSolver:
         return self.solver.config
 
     def lane_count(self, payload: Any) -> int:
-        arr = jnp.asarray(payload)
-        return 1 if arr.ndim == 1 else arr.shape[0]
+        return 1 if np.ndim(payload) == 1 else np.shape(payload)[0]
 
     def signature(self, payload: Any) -> Hashable:
-        arr = jnp.asarray(payload)
-        n = arr.shape[-1]
+        n = np.shape(payload)[-1]
         if n != self.config.n:
             raise ValueError(f"payload N={n} != solver N={self.config.n}")
         return n
@@ -221,24 +218,22 @@ class RetrievalEngineSolver:
         with spans.span(spans.SOLVE):
             res = api.retrieve(cfg_b, params_b, batch, lane_keys)
         with spans.span(spans.SPLIT):
-            self._observe_settle(res, total)
+            with spans.span(spans.SYNC):
+                host = jax.device_get(res)
+            self._observe_settle(host, total)
             n = self.config.n
             out: List[Any] = []
             offset = 0
             for p, c in zip(payloads, counts):
-                # Gather by an index *operand* rather than a static slice: the
-                # executable is keyed by the lane count only, not by where the
-                # request landed in the slab (a static [offset:offset+c] start
-                # compiles one slicer per offset — unbounded under live load).
-                idx = jnp.arange(offset, offset + c, dtype=jnp.int32)
+                rows = slice(offset, offset + c)
                 r = dynamics.ONNResult(
-                    final_phase=res.final_phase[idx, :n],
-                    final_sigma=res.final_sigma[idx, :n],
-                    settle_cycle=res.settle_cycle[idx],
-                    settled=res.settled[idx],
-                    cycled=res.cycled[idx],
+                    final_phase=host.final_phase[rows, :n],
+                    final_sigma=host.final_sigma[rows, :n],
+                    settle_cycle=host.settle_cycle[rows],
+                    settled=host.settled[rows],
+                    cycled=host.cycled[rows],
                 )
-                if jnp.asarray(p).ndim == 1:  # single-lane payload → unbatched result
+                if np.ndim(p) == 1:  # single-lane payload → unbatched result
                     r = jax.tree.map(lambda x: x[0], r)
                 out.append(r)
                 offset += c
@@ -327,7 +322,7 @@ class RetrievalEngineSolver:
             settled=res.settled[idx],
             cycled=res.cycled[idx],
         )
-        if jnp.asarray(payload).ndim == 1:  # single-lane payload → unbatched
+        if np.ndim(payload) == 1:  # single-lane payload → unbatched
             r = jax.tree.map(lambda x: x[0], r)
         return r
 
@@ -340,45 +335,27 @@ class RetrievalEngineSolver:
     # -- measured settle-cycle cost model ----------------------------------
 
     def _observe_settle(self, res: Any, lanes: int) -> None:
-        """Queue one slab's measured settle cycles for the EMA (real lanes
-        only; unsettled/cycled lanes are charged the worst case).
-
-        Only the tiny on-device mean is enqueued — no host sync here, so a
-        drain keeps dispatching slabs without waiting for each solve to
-        finish.  The fold to host happens lazily at quote/stats time
-        (:meth:`_fold_pending`)."""
+        """Fold one slab's measured settle cycles into the EMA (real lanes
+        only; unsettled/cycled lanes are charged the worst case).  ``res``
+        holds host rows: the slab's result already fetched by the caller."""
         if lanes <= 0:
             return
         mc = self.config.max_cycles
-        eff = jnp.where(res.settled[:lanes], res.settle_cycle[:lanes] + 1, mc)
-        self._settle_pending.append(jnp.mean(eff.astype(jnp.float32)))
+        eff = np.where(res.settled[:lanes], res.settle_cycle[:lanes] + 1, mc)
+        mean_eff = float(np.mean(eff.astype(np.float32)))
+        a = self.SETTLE_EMA_ALPHA
+        self._settle_ema = (
+            mean_eff
+            if self._settle_ema is None
+            else (1 - a) * self._settle_ema + a * mean_eff
+        )
+        self._settle_obs += 1
 
-    def _fold_pending(self, block: bool = True) -> None:
-        """Fold queued slab means into the EMA.  ``block=False`` folds only
-        results whose computation already finished (the post-slab cost-model
-        path uses it to stay off the device's critical path)."""
-        remaining: List[jax.Array] = []
-        for arr in self._settle_pending:
-            if not block and not arr.is_ready():
-                remaining.append(arr)
-                continue
-            with spans.span(spans.SYNC):
-                mean_eff = float(arr)
-            a = self.SETTLE_EMA_ALPHA
-            self._settle_ema = (
-                mean_eff
-                if self._settle_ema is None
-                else (1 - a) * self._settle_ema + a * mean_eff
-            )
-            self._settle_obs += 1
-        self._settle_pending = remaining
-
-    def expected_cycles(self, block: bool = False) -> float:
+    def expected_cycles(self) -> float:
         """Quoted oscillation cycles per solve: worst-case ``max_cycles``
         blended toward the measured settle-cycle EMA as slabs are observed
         (the early-exit batched solve really does stop at the EMA, so the
         quote converges on executed work instead of the scan bound)."""
-        self._fold_pending(block=block)
         mc = float(self.config.max_cycles)
         if self._settle_ema is None:
             return mc
@@ -387,12 +364,11 @@ class RetrievalEngineSolver:
 
     def stats(self) -> Dict[str, Any]:
         """Measured settle-cycle state (surfaced by ``Engine.stats()``)."""
-        self._fold_pending(block=True)
         return {
             "max_cycles": self.config.max_cycles,
             "settle_ema_cycles": self._settle_ema,
             "settle_slabs_observed": self._settle_obs,
-            "expected_cycles": round(self.expected_cycles(block=True), 3),
+            "expected_cycles": round(self.expected_cycles(), 3),
             "hot_swaps": self._swaps,
             "autotune": autotune.cache_info(),
         }
@@ -521,10 +497,10 @@ class MaxCutEngineSolver:
         return 1
 
     def signature(self, payload: Any) -> Hashable:
-        arr = jnp.asarray(payload)
-        if arr.ndim != 2 or arr.shape[0] != arr.shape[1]:
-            raise ValueError(f"max-cut payload must be square, got {arr.shape}")
-        return arr.shape[0]
+        shape = np.shape(payload)
+        if len(shape) != 2 or shape[0] != shape[1]:
+            raise ValueError(f"max-cut payload must be square, got {shape}")
+        return shape[0]
 
     def bucket(self, signature: int, n_policy: bucketing.NBucketPolicy) -> int:
         return bucketing.bucket_n(signature, n_policy)
@@ -568,18 +544,18 @@ class MaxCutEngineSolver:
                 true_n=true_n,
             )
         with spans.span(spans.SPLIT):
-            out = []
-            for i, p in enumerate(payloads):
-                n = jnp.asarray(p).shape[0]
-                out.append(
-                    ising_lib.MaxCutResult(
-                        sigma=res.sigma[i, :n],
-                        cut_value=res.cut_value[i],
-                        trace=res.trace[i],
-                        replica_cuts=res.replica_cuts[i],
-                        sweeps_run=res.sweeps_run[i],
-                    )
+            with spans.span(spans.SYNC):
+                host = jax.device_get(res)
+            out = [
+                ising_lib.MaxCutResult(
+                    sigma=host.sigma[i, : np.shape(p)[0]],
+                    cut_value=host.cut_value[i],
+                    trace=host.trace[i],
+                    replica_cuts=host.replica_cuts[i],
+                    sweeps_run=host.sweeps_run[i],
                 )
+                for i, p in enumerate(payloads)
+            ]
         return out
 
     def stats(self) -> Dict[str, Any]:

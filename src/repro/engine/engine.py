@@ -68,7 +68,13 @@ class EngineSolver(Protocol):
         keys: List[jax.Array],
         batch_bucket: int,
     ) -> List[Any]:
-        """Serve ``payloads`` (Σ lanes ≤ batch_bucket) in one padded batch."""
+        """Serve ``payloads`` (Σ lanes ≤ batch_bucket) in one padded batch.
+
+        Returns one result per payload, in order, as host (numpy) values:
+        the slab's result crosses to the host once and is cut per request
+        there, as the streaming path's harvest does.  (The LM decode adapter
+        still returns its tokens as a device array.)
+        """
         ...
 
     def cost_units(self, bucket_sig: Hashable, batch_bucket: int) -> float:

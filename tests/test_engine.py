@@ -333,3 +333,101 @@ def test_fpga_tradeoff_quotes_partitioned_design_past_the_wall():
     assert quoted == pytest.approx(
         hw.partitioned_time_to_solution(4096, k, 100.0, bits)
     )
+
+
+# ---------------------------------------------------------------------------
+# Host results: one fetch per slab, payload shapes read on the host
+# ---------------------------------------------------------------------------
+
+
+def _retrieval_slab():
+    """A retrieval solver, a mix of single- and multi-lane payloads that fill
+    one bucket-8 slab, and each request's solve alone (a single lane as a
+    batch of one, unbatched)."""
+    s = _solver(8, 20, max_cycles=61)
+    rng = np.random.default_rng(17)
+    shapes = [(20,), (3, 20), (20,), (2, 20)]
+    payloads = [rng.choice([-1, 1], shape).astype(np.int8) for shape in shapes]
+
+    def alone(p, key):
+        res = s.solve(jnp.atleast_2d(jnp.asarray(p)))
+        return jax.tree.map(lambda x: x[0], res) if p.ndim == 1 else res
+
+    return s, [(p, None) for p in payloads], alone
+
+
+def _maxcut_slab():
+    """A max-cut solver and graphs of two sizes padded to one N bucket."""
+    s = api.MaxCutSolver(sweeps=6, replicas=2)
+    root = jax.random.PRNGKey(19)
+    return s, [
+        (
+            np.asarray(random_graph(jax.random.fold_in(root, i), n, 0.5)),
+            jax.random.fold_in(root, 100 + i),
+        )
+        for i, n in enumerate((20, 24, 24, 20))
+    ], s.solve
+
+
+@pytest.mark.parametrize("make", [_retrieval_slab, _maxcut_slab], ids=["retrieval", "maxcut"])
+def test_drained_slab_results_are_host_values_fetched_once(make, monkeypatch):
+    """A mixed slab's results are numpy values, bit-identical in value, dtype
+    and shape to solving each request alone, from one device_get per slab."""
+    from repro.engine import adapters
+
+    solver, requests, alone = make()
+    eng = engine_lib.Engine(jax.random.PRNGKey(15), batch_buckets=(1, 2, 4, 8))
+    eng.install("w", solver.as_engine_solver())
+    futs = [eng.submit(engine_lib.Request("w", p, key=k)) for p, k in requests]
+    fetches = []
+    device_get = adapters.jax.device_get
+    monkeypatch.setattr(
+        adapters.jax, "device_get", lambda x: fetches.append(1) or device_get(x)
+    )
+    stats = eng.drain()
+    monkeypatch.undo()
+    assert stats["slabs"] == 1 and stats["completed"] == len(requests)
+    assert len(fetches) == stats["slabs"]
+    for (p, k), fut in zip(requests, futs):
+        got, want = fut.result(), alone(p, k)
+        for g, w in zip(got, want):
+            assert isinstance(g, (np.ndarray, np.generic))
+            w = np.asarray(w)
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(g, w)
+
+
+def _retrieval_validate():
+    adapter = _solver(9, 12, max_cycles=67).as_engine_solver()
+    good = [(np.ones((12,), np.int8), 1, 12), (np.ones((3, 12), np.int8), 3, 12)]
+    bad = [(np.ones((2, 9), np.int8), "N=9")]
+    return adapter, good, bad
+
+
+def _maxcut_validate():
+    adapter = api.MaxCutSolver(sweeps=4).as_engine_solver()
+    good = [(np.zeros((10, 10), np.int8), 1, 10), (np.zeros((24, 24), np.int8), 1, 24)]
+    bad = [(np.zeros((10, 12), np.int8), "square"), (np.zeros((10,), np.int8), "square")]
+    return adapter, good, bad
+
+
+@pytest.mark.parametrize(
+    "make", [_retrieval_validate, _maxcut_validate], ids=["retrieval", "maxcut"]
+)
+def test_validate_reads_payload_shapes_on_the_host(make, monkeypatch):
+    """lane_count and signature read numpy payloads without a transfer: the
+    same counts and signatures, and the same ValueError on a wrong shape."""
+    adapter, good, bad = make()
+
+    def no_transfer(*args, **kwargs):
+        raise AssertionError("payload copied to the device")
+
+    monkeypatch.setattr(jnp, "asarray", no_transfer)
+    monkeypatch.setattr(jax, "device_put", no_transfer)
+    with jax.transfer_guard_host_to_device("disallow"):
+        for payload, lanes, sig in good:
+            assert adapter.lane_count(payload) == lanes
+            assert adapter.signature(payload) == sig
+        for payload, match in bad:
+            with pytest.raises(ValueError, match=match):
+                adapter.signature(payload)

@@ -119,7 +119,7 @@ def test_untraced_records_nothing_and_results_match(workload, monkeypatch):
 
 
 def test_traced_spans_nest_and_link(traced):
-    workload, recs, _ = traced
+    _, recs, _ = traced
     by_index = {r.index: r for r in recs}
 
     def children(rec, name):
@@ -131,11 +131,8 @@ def test_traced_spans_nest_and_link(traced):
     (drain,) = [r for r in recs if r.name == spans.DRAIN]
     slabs = children(drain, spans.SLAB)
     assert slabs and len(children(drain, spans.STATS)) == 1
-    if workload == "retrieval":  # each slab's settle-cycle mean is read back
-        syncs = [r for r in recs if r.name == spans.SYNC]
-        assert len(syncs) == len(slabs)
-        for sync in syncs:
-            inside(sync, drain)
+    # Each slab's result crosses to the host once, inside its split.
+    assert len([r for r in recs if r.name == spans.SYNC]) == len(slabs)
     for slab in slabs:
         inside(slab, drain)
         assert slab.attrs["workload"] in ("mem", "cuts")
@@ -144,6 +141,9 @@ def test_traced_spans_nest_and_link(traced):
         for name in (spans.PACK, spans.SOLVE, spans.SPLIT):
             (child,) = children(slab, name)
             inside(child, slab)
+        (split,) = children(slab, spans.SPLIT)
+        (sync,) = children(split, spans.SYNC)
+        inside(sync, split)
     submits = [r for r in recs if r.name == spans.SUBMIT]
     served = sorted(i for s in slabs for i in s.attrs["ids"])
     assert sorted(s.attrs["request"] for s in submits) == served
