@@ -15,9 +15,10 @@ full width, and checks every result bit for bit against the plain
 * continuous serving — a handful of the retrieval requests served through
   a ``serving.ContinuousEngine`` tick loop.
 
-With ``--chips 4`` it runs only the row-sharded solve: ``ShardPlan(batch=1,
-model=4)`` at N=4096 and at N=506 (zero-row-padded route), compared with the
-unsharded solve in the same process.
+With ``--chips 4`` it runs only the row-sharded solve: a ``RetrievalSolver``
+holding ``ShardPlan(batch=1, model=4)``, installed on an engine, at N=4096
+and at N=506 (zero-row-padded route), compared with the unsharded solve in
+the same process.
 
 Each phase prints one ``smoke-phase`` line with its compile and run seconds
 (smoke timings from the host clock around ``block_until_ready``, not
@@ -51,7 +52,6 @@ from repro.data import patterns as pat  # noqa: E402
 from repro.distributed import ShardPlan  # noqa: E402
 from repro.engine import Engine, Request, bucketing  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
-from repro.launch.retrieve import plan_context  # noqa: E402
 from repro.serving import ContinuousEngine  # noqa: E402
 
 SEED = 0
@@ -288,13 +288,25 @@ def sharded_phase(chips: int) -> None:
         solver = api.RetrievalSolver(config=cfg, params=api.make_params(cfg, seeded_weights(n, n)))
         sigma = seeded_spins(n, SHARDED_LANES, n + 1)
         ref, ref_compile_s, ref_run_s = cold_and_warm(lambda: solver.solve(sigma))
-        sharded, ctx = plan_context(solver, plan)
-        with ctx:
-            res, compile_s, run_s = cold_and_warm(lambda: sharded.solve(sigma))
-            kernel = has_kernel(lambda p, s: api.retrieve(cfg, p, s), sharded.params, sigma)
+        # The planned solver on an engine: the adapter places W and runs
+        # every solve under the plan; nothing here enters the plan's context.
+        engine = Engine(jax.random.PRNGKey(SEED), n_policy="exact")
+        adapter = engine.install(
+            "retrieval", dataclasses.replace(solver, plan=plan).as_engine_solver())
+
+        def served():
+            fut = engine.submit(Request("retrieval", sigma))
+            engine.drain()
+            return fut.result()
+
+        res, compile_s, run_s = cold_and_warm(served)
         check(same(res, ref), f"sharded N={n}: results differ from the unsharded solve")
+        check(adapter.stats()["sharded_slabs"] == 2, f"sharded N={n}: slabs not solved sharded")
+        weights = adapter.solver.params.weights
+        with plan.context():
+            kernel = has_kernel(lambda p, s: api.retrieve(cfg, p, s), adapter.solver.params, sigma)
         check(kernel, f"sharded N={n}: no tpu_custom_call in the compiled solve")
-        shards = sharded.params.weights.addressable_shards
+        shards = weights.addressable_shards
         devices = {s.device for s in shards}
         full = int(np.asarray(solver.params.weights).nbytes)
         per_device = sorted(int(s.data.nbytes) for s in shards)

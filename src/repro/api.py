@@ -32,6 +32,7 @@ Quickstart::
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Optional, Protocol, runtime_checkable
 
@@ -66,6 +67,7 @@ from repro.core.ising import (  # noqa: F401 — re-exported API
 )
 from repro.core.learning import diederich_opper_i
 from repro.core.quantization import quantize_weights
+from repro.distributed.plan import ShardPlan
 from repro.engine.registry import register_solver
 
 
@@ -89,10 +91,17 @@ class RetrievalSolver:
     ``solve`` takes a (B, N) ±1 batch of (corrupted) patterns and an optional
     key — required only when the config draws randomness (rtl sync_jitter); a
     single key is split into one subkey per request.
+
+    ``plan`` (a :class:`repro.distributed.ShardPlan`) belongs to the solver:
+    installed on an engine, the adapter builds the plan's mesh once, places
+    the coupling matrix for it (row-sharded over ``"model"``) and runs every
+    solve under it, from whatever thread drains.  ``solve`` runs under it too.
+    ``None`` (or a one-device plan) solves on one device.
     """
 
     config: ONNConfig
     params: OnnParams
+    plan: Optional[ShardPlan] = None
 
     @classmethod
     def from_patterns(
@@ -109,10 +118,13 @@ class RetrievalSolver:
         return cls(config=cfg, params=make_params(cfg, qw.values))
 
     def solve(self, instance: jax.Array, key: Optional[jax.Array] = None) -> ONNResult:
-        return retrieve(self.config, self.params, instance, key)
+        planned = self.plan is not None and self.plan.devices > 1
+        with self.plan.context() if planned else contextlib.nullcontext():
+            return retrieve(self.config, self.params, instance, key)
 
     def as_engine_solver(self):
-        """This solver as an installable ``repro.engine`` workload adapter."""
+        """This solver as an installable ``repro.engine`` workload adapter
+        (it carries ``plan`` to the adapter)."""
         from repro.engine.adapters import RetrievalEngineSolver
 
         return RetrievalEngineSolver(solver=self)

@@ -11,6 +11,7 @@ concurrently.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Dict, Hashable, List, Optional, Sequence, Tuple
 
@@ -126,11 +127,18 @@ class RetrievalEngineSolver:
             solver = RetrievalSolver.from_patterns(jnp.asarray(xi), **cfg_kwargs)
         elif cfg_kwargs or xi is not None:
             raise TypeError("pass either a built solver or xi= + config kwargs")
+        plan = getattr(solver, "plan", None)
+        # A plan over one device is no plan: nothing below runs for it.
+        self._plan = plan if plan is not None and plan.devices > 1 else None
+        self._mesh = self._plan.make_mesh() if self._plan is not None else None
+        if self._plan is not None:
+            solver = dataclasses.replace(solver, params=self._place(solver.params))
         self.solver = solver
         self._padded: Dict[int, Tuple[Any, Any]] = {}
         self._settle_ema: Optional[float] = None
         self._settle_obs: int = 0
         self._swaps: int = 0
+        self._sharded_slabs: int = 0
 
     @property
     def config(self):
@@ -151,9 +159,59 @@ class RetrievalEngineSolver:
     def _padded_instance(self, n_bucket: int):
         if n_bucket not in self._padded:
             cfg_b = dynamics.pad_config(self.config, n_bucket)
-            params_b = dynamics.pad_params(self.config, self.solver.params, n_bucket)
+            params_b = self._place(
+                dynamics.pad_params(self.config, self.solver.params, n_bucket))
             self._padded[n_bucket] = (cfg_b, params_b)
         return self._padded[n_bucket]
+
+    # -- the solver's ShardPlan ----------------------------------------------
+
+    def _place(self, params: dynamics.OnnParams) -> dynamics.OnnParams:
+        """``params`` in the plan's at-rest placement (as they are without one)."""
+        if self._plan is None:
+            return params
+        from repro.distributed import sharding
+
+        return sharding.shard_onn_params(params, self._plan, self._mesh)
+
+    def _planned(self):
+        """The plan's context around a solve that traces (a no-op without one)."""
+        if self._plan is None:
+            return contextlib.nullcontext()
+        return self._plan.context(self._mesh)
+
+    def _slab_sharding(self, width: int):
+        """The packed σ slab's sharding on the plan's mesh: lanes over
+        ``"data"`` when the plan splits them, else replicated."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        lanes = "data" if self._plan.batch > 1 and width % self._plan.batch == 0 else None
+        return NamedSharding(self._mesh, P(lanes, None))
+
+    def slab_attrs(self, bucket_sig: int, batch_bucket: int) -> Dict[str, Any]:
+        """Attributes of an ``engine.slab`` span that runs sharded: the plan,
+        its devices, the coupling bytes each device holds at rest and the
+        bytes each device puts on the wire per cycle to combine its row
+        block's fields (the psum buffer: lanes x N bucket, int32, or int8
+        on the compressed wire; 0 where W is not row-sharded)."""
+        if self._plan is None:
+            return {}
+        plan = self._plan
+        _, params_b = self._padded_instance(bucket_sig)
+        w_bytes = max(s.data.nbytes for s in params_b.weights.addressable_shards)
+        combine = 0
+        if plan.model_sharded:
+            lanes = batch_bucket
+            if plan.batch > 1 and batch_bucket % plan.batch == 0:
+                lanes //= plan.batch
+            rows = -(-bucket_sig // plan.model) * plan.model  # W rows, zero-padded
+            combine = lanes * rows * (1 if plan.compressed else 4)
+        return {
+            "plan": f"{plan.batch}x{plan.model}",
+            "devices": plan.devices,
+            "w_bytes_per_device": int(w_bytes),
+            "combine_bytes_per_cycle": int(combine),
+        }
 
     def _draws_randomness(self) -> bool:
         return self.config.mode == "rtl" and self.config.sync_jitter
@@ -182,10 +240,11 @@ class RetrievalEngineSolver:
         if weights.dtype != jnp.int8:
             raise TypeError(f"hot swap needs int8 weights, got {weights.dtype}")
         dynamics.validate_weights(weights, cfg.weight_bits)
+        params = self._place(params)
         self.solver = dataclasses.replace(self.solver, params=params)
         for nb in list(self._padded):
             cfg_b, _ = self._padded[nb]
-            self._padded[nb] = (cfg_b, dynamics.pad_params(cfg, params, nb))
+            self._padded[nb] = (cfg_b, self._place(dynamics.pad_params(cfg, params, nb)))
         self._swaps += 1
 
     def solve_bucket(
@@ -207,6 +266,8 @@ class RetrievalEngineSolver:
             if total < batch_bucket:
                 pad_rows = jnp.ones((batch_bucket - total, bucket_sig), jnp.int8)
                 batch = jnp.concatenate([batch, pad_rows], axis=0)
+            if self._plan is not None:  # one transfer to the slab's place on the mesh
+                batch = jax.device_put(batch, self._slab_sharding(batch_bucket))
 
             lane_keys = None
             if self._draws_randomness():
@@ -215,8 +276,10 @@ class RetrievalEngineSolver:
                     per_lane.extend(jax.random.split(k, c))
                 lane_keys = _stack_keys(per_lane, batch_bucket)
 
-        with spans.span(spans.SOLVE):
+        with spans.span(spans.SOLVE), self._planned():
             res = api.retrieve(cfg_b, params_b, batch, lane_keys)
+        if self._plan is not None:
+            self._sharded_slabs += 1
         with spans.span(spans.SPLIT):
             with spans.span(spans.SYNC):
                 host = jax.device_get(res)
@@ -253,12 +316,11 @@ class RetrievalEngineSolver:
         """A fresh all-dead slab of ``width`` lanes at the N bucket."""
         autotune.warm(n=bucket_sig, batch=width)
         cfg_b, params_b = self._padded_instance(bucket_sig)
-        return RetrievalSlab(
-            cfg=cfg_b,
-            params=params_b,
-            state=dynamics.dead_batch_state(cfg_b, width),
-            width=width,
-        )
+        with self._planned():
+            state = dynamics.dead_batch_state(cfg_b, width)
+        if self._plan is not None:
+            self._sharded_slabs += 1
+        return RetrievalSlab(cfg=cfg_b, params=params_b, state=state, width=width)
 
     def admit(
         self,
@@ -278,16 +340,18 @@ class RetrievalEngineSolver:
             lane_keys = _stack_keys(
                 list(jax.random.split(key, lanes2d.shape[0])), lanes2d.shape[0]
             )
-        sub = dynamics.init_batch_state(
-            slab.cfg, dynamics.initial_phase(slab.cfg, sigma), lane_keys
-        )
-        slab.state = dynamics.install_lanes(
-            slab.state, sub, jnp.asarray(slots, jnp.int32)
-        )
+        with self._planned():
+            sub = dynamics.init_batch_state(
+                slab.cfg, dynamics.initial_phase(slab.cfg, sigma), lane_keys
+            )
+            slab.state = dynamics.install_lanes(
+                slab.state, sub, jnp.asarray(slots, jnp.int32)
+            )
 
     def advance(self, slab: RetrievalSlab) -> None:
         """Advance every live lane by one settle-chunk (one device dispatch)."""
-        slab.state = dynamics.advance_chunk(slab.cfg, slab.params, slab.state)
+        with self._planned():
+            slab.state = dynamics.advance_chunk(slab.cfg, slab.params, slab.state)
 
     def done_mask(self, slab: RetrievalSlab) -> Any:
         """(width,) host bool array: lanes whose results are final."""
@@ -370,6 +434,7 @@ class RetrievalEngineSolver:
             "settle_slabs_observed": self._settle_obs,
             "expected_cycles": round(self.expected_cycles(), 3),
             "hot_swaps": self._swaps,
+            "sharded_slabs": self._sharded_slabs,
             "autotune": autotune.cache_info(),
         }
 

@@ -339,6 +339,7 @@ class Engine:
         with spans.span(
             spans.SLAB, slab=next(self._slab_seq), workload=workload, bucket=bucket_sig,
             requests=len(slab), lanes=lanes, width=bb, ids=[p.seq for p in slab],
+            **self._slab_attrs(solver, bucket_sig, bb),
         ) as slab_span:
             try:
                 results = solver.solve_bucket(
@@ -403,6 +404,12 @@ class Engine:
                 return self.stats()
 
     # -- introspection -----------------------------------------------------
+
+    @staticmethod
+    def _slab_attrs(solver: EngineSolver, bucket_sig: Hashable, width: int) -> Dict[str, Any]:
+        """Span attributes the adapter adds to a slab (a sharded slab's plan)."""
+        attrs = getattr(solver, "slab_attrs", None)
+        return attrs(bucket_sig, width) if callable(attrs) else {}
 
     @staticmethod
     def _fpga_tradeoff(solver: EngineSolver, bucket_sig: Hashable):

@@ -28,7 +28,7 @@ SUBMIT = "engine.submit"
 VALIDATE = "engine.validate"  # lane_count, signature, bucket
 QUOTE = "engine.quote"  # the planner's estimate with its cost units and FPGA quotes
 DRAIN = "engine.drain"
-SLAB = "engine.slab"  # one solve_bucket call
+SLAB = "engine.slab"  # one solve_bucket call; a sharded one adds the adapter's slab_attrs
 PACK = "engine.pack"  # payloads and keys into one padded slab
 SOLVE = "engine.solve"  # the jitted solve's call: its dispatch, not its device time
 SPLIT = "engine.split"  # the slab's result cut into per-request results

@@ -28,7 +28,6 @@ Usage:
 from __future__ import annotations
 
 import argparse
-import contextlib
 import dataclasses
 import json
 import time
@@ -42,7 +41,6 @@ import numpy as np
 from repro.api import RetrievalSolver
 from repro.data import patterns as pat
 from repro.distributed import ShardPlan, plan_of_legacy_shard_batch
-from repro.distributed import sharding as shard_lib
 from repro.engine import DEFAULT_BATCH_BUCKETS, Engine, Request
 from repro.launch.compile_cache import enable_compile_cache
 
@@ -91,22 +89,6 @@ def batch_mesh() -> Optional[jax.sharding.Mesh]:
     return jax.sharding.Mesh(
         np.asarray(devices).reshape(len(devices), 1), ("data", "model")
     )
-
-
-def plan_context(solver, plan: Optional[ShardPlan]):
-    """(resharded solver, active plan context) for serving under a plan.
-
-    Places the coupling matrix for the plan's layout (row-sharded over the
-    ``"model"`` axis when it model-parallelizes and N divides) and returns
-    the context manager that activates the plan for every solve traced
-    inside.  ``plan=None`` (or a trivial 1×1 plan) is a no-op.
-    """
-    if plan is None or plan.devices == 1:
-        return solver, contextlib.nullcontext()
-    mesh = plan.make_mesh()
-    params = shard_lib.shard_onn_params(solver.params, plan, mesh)
-    solver = dataclasses.replace(solver, params=params)
-    return solver, plan.context(mesh)
 
 
 def _plan_of_mesh_kwarg(
@@ -172,18 +154,18 @@ def serve_requests(
     ckeys = jax.random.split(k2, n_requests)
     corrupted = jax.vmap(lambda t, k: pat.corrupt(t, k, corruption))(targets, ckeys)
 
-    solver, rules_ctx = plan_context(solver, plan)
+    if plan is not None:
+        solver = dataclasses.replace(solver, plan=plan)
     eng = Engine(
         k_engine, batch_buckets=batch_buckets, n_policy=n_policy, coalesce=coalesce
     )
     eng.install("retrieval", solver.as_engine_solver())
 
     t0 = time.perf_counter()
-    with rules_ctx:
-        futures = [
-            eng.submit(Request("retrieval", corrupted[i])) for i in range(n_requests)
-        ]
-        stats = eng.drain()
+    futures = [
+        eng.submit(Request("retrieval", corrupted[i])) for i in range(n_requests)
+    ]
+    stats = eng.drain()
     sigma = jnp.stack([f.result().final_sigma for f in futures])
     settle_cycle = jnp.stack([f.result().settle_cycle for f in futures])
     settled = jnp.stack([f.result().settled for f in futures])

@@ -10,9 +10,10 @@ Send SIGTERM to observe the graceful drain: in-flight slabs complete,
 queued requests are rejected (or served with ``--drain-queue``), the
 heartbeat file goes stale after exit.
 
-``--mesh BxM`` runs the whole daemon under a
-:class:`repro.distributed.ShardPlan`: streaming slabs split B ways over the
-data axis and every coupling sum runs the M-way row-sharded collective.
+``--mesh BxM`` serves under a :class:`repro.distributed.ShardPlan`:
+streaming slabs split B ways over the data axis and every coupling sum runs
+the M-way row-sharded collective.  The retrieval solvers hold the plan (their
+couplings placed for it); Max-Cut runs under the plan's ambient context.
 ``--mesh auto`` sizes the plan with ``repro.distributed.ft.propose_mesh`` —
 the same elastic re-mesh policy the daemon's fault-tolerance hooks
 (heartbeat, preemption guard, per-slab step monitors) assume after a device
@@ -70,7 +71,7 @@ def run_daemon(
         tenant_weights=dict(tenants),
         max_queue_lanes=max_queue_lanes,
     )
-    serving.install_mixed_workloads(eng, sweeps=sweeps, small_ckpt=onn_ckpt)
+    serving.install_mixed_workloads(eng, sweeps=sweeps, small_ckpt=onn_ckpt, plan=plan)
     requests = serving.mixed_requests(n_requests, seed=seed, tenants=tenants)
     if ticked > 0:  # deterministic per-tick arrivals (no wall clock)
         source = serving.ticked_source(requests, per_tick=ticked)
@@ -84,6 +85,7 @@ def run_daemon(
         drain_queue_on_term=drain_queue_on_term,
         max_ticks=max_ticks,
     )
+    # The retrieval solvers hold the plan; Max-Cut's solves trace under it here.
     plan_ctx = (
         contextlib.nullcontext() if plan is None or plan.devices == 1
         else plan.context()

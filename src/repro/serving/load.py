@@ -9,10 +9,12 @@ is what makes sustained-throughput and tail-latency numbers honest.
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import Any, Iterator, List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.ising import random_graph
@@ -29,6 +31,7 @@ def install_mixed_workloads(
     sweeps: int = 8,
     replicas: int = 1,
     small_ckpt: Optional[str] = None,
+    plan: Optional[Any] = None,
 ) -> None:
     """Install the stream's three workloads (same shapes as the engine bench):
     ``small`` retrieval (N=42), ``large`` retrieval (N=100), ``cuts`` max-cut.
@@ -37,16 +40,23 @@ def install_mixed_workloads(
     (:func:`repro.checkpoint.load_onn`) instead of training in-process — the
     daemon-restart path after ``repro.launch.train_onn`` persisted a trained
     matrix.  The checkpoint must be N=42 (the stream's small probes).
-    """
-    if small_ckpt is None:
-        engine.install("small", "retrieval", xi=pat.load_dataset("7x6"))
-    else:
-        from repro.engine.adapters import RetrievalEngineSolver
 
+    ``plan`` (a :class:`repro.distributed.ShardPlan`) is held by both
+    retrieval solvers, whose adapters place the couplings and solve under
+    it; Max-Cut holds no plan and runs under the caller's ``plan.context()``.
+    """
+    from repro import api
+    from repro.engine.adapters import RetrievalEngineSolver
+
+    if small_ckpt is None:
+        small = api.RetrievalSolver.from_patterns(jnp.asarray(pat.load_dataset("7x6")))
+    else:
+        small = restore_retrieval(small_ckpt, n=42)
+    large = api.RetrievalSolver.from_patterns(jnp.asarray(pat.load_dataset("10x10")))
+    for name, solver in (("small", small), ("large", large)):
         engine.install(
-            "small", RetrievalEngineSolver(solver=restore_retrieval(small_ckpt, n=42))
+            name, RetrievalEngineSolver(solver=dataclasses.replace(solver, plan=plan))
         )
-    engine.install("large", "retrieval", xi=pat.load_dataset("10x10"))
     engine.install("cuts", "maxcut", sweeps=sweeps, replicas=replicas)
 
 

@@ -1,6 +1,7 @@
 """Model-parallel (row-sharded coupling matrix) multi-device tests.
 
-Each test spawns a subprocess with ``XLA_FLAGS`` forcing 8 host devices —
+Each test spawns a subprocess with ``XLA_FLAGS`` forcing 8 host devices (4
+for the planned-engine tests) —
 the main test process must keep seeing 1 device (see tests/test_sharding.py,
 which pins that invariant).  Every subprocess prints one JSON line; the
 assertions run here so failures carry readable context.
@@ -16,7 +17,13 @@ Covered (ISSUE satellite: CI-runnable multi-device coverage):
     advance with lanes installed while the slab is in flight);
   * the compressed int8 collectives: error-feedback round-trip of
     ``compressed_psum_mean`` under shard_map, and a bit-exact
-    ``ShardPlan(compressed=True)`` solve in the small-field regime.
+    ``ShardPlan(compressed=True)`` solve in the small-field regime;
+  * a ``RetrievalSolver`` that holds ``ShardPlan(1x4)`` installed on an
+    engine: W placed in four row shards, a mixed slab bit-exact with the
+    unplanned engine and the per-lane oracle, from another thread and from a
+    ``ContinuousEngine`` with no ambient context, the slab span's plan
+    attributes, a hot swap that keeps the row sharding, and the daemon's
+    retrieval workloads holding the plan.
 """
 
 import json
@@ -310,3 +317,250 @@ def test_compressed_collectives_roundtrip():
         f"EF telescoping identity violated: residual {result['ef_residual']}"
     )
     assert result["compressed_solve_exact"], "compressed-plan solve diverged"
+
+
+# ---------------------------------------------------------------------------
+# The plan held by an installed solver (engine path, four devices)
+# ---------------------------------------------------------------------------
+
+_PLANNED_ENGINE_SCRIPT = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import dataclasses
+    import json
+    import threading
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+
+    from repro import api
+    from repro.core import dynamics
+    from repro.core.learning import hebbian
+    from repro.core.quantization import quantize_weights
+    from repro.distributed import ShardPlan
+    from repro.distributed import sharding as shard_lib
+    from repro.engine import Engine, Request, engine as engine_mod, spans
+    from repro.serving import ContinuousEngine
+
+    assert jax.device_count() == 4
+    N, P_STORED = 512, 16
+    rng = np.random.default_rng(16)
+
+    def library(seed):
+        xi = np.where(np.random.default_rng(seed).random((P_STORED, N)) < 0.5, 1, -1)
+        w = quantize_weights(hebbian(jnp.asarray(xi, jnp.int8), self_coupling=False), 5)
+        return xi.astype(np.int8), w.values
+
+    def probes(xi, lanes):
+        clean = xi[rng.integers(0, P_STORED, lanes)]
+        flip = rng.random((lanes, N)) < 0.15
+        return np.where(flip, -clean, clean).astype(np.int8)
+
+    def same(a, b):
+        la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+        return len(la) == len(lb) and all(
+            x.shape == y.shape and x.dtype == y.dtype and np.array_equal(x, y)
+            for x, y in zip(map(np.asarray, la), map(np.asarray, lb)))
+
+    def all_same(xs, ys):
+        return len(xs) == len(ys) and all(same(x, y) for x, y in zip(xs, ys))
+
+    def shard_bytes(params):
+        shards = params.weights.addressable_shards
+        return len({s.device for s in shards}), sorted(int(s.data.nbytes) for s in shards)
+
+    def row_sharded(params):
+        return params.weights.sharding.spec == jax.sharding.PartitionSpec("model", None)
+
+    xi, w = library(0)
+    cfg = api.ONNConfig(n=N, backend="pallas", max_cycles=40)
+    plan = ShardPlan(batch=1, model=4)
+    plain = api.RetrievalSolver(config=cfg, params=api.make_params(cfg, w))
+    planned = dataclasses.replace(plain, plan=plan)
+    payloads = [probes(xi, 1)[0], probes(xi, 8), probes(xi, 1)[0]]
+
+    attrs = []
+    orig_span = spans.span
+
+    def spy(name, **kw):
+        if name == spans.SLAB:
+            attrs.append(kw)
+        return orig_span(name, **kw)
+
+    engine_mod.spans.span = spy
+
+    # Trace-time count of the row-sharded collective: a solve traced outside
+    # the plan's context never reaches it.
+    collective_traces = [0]
+    orig_collective = dynamics._model_sharded_sum
+
+    def counted(*args, **kw):
+        collective_traces[0] += 1
+        return orig_collective(*args, **kw)
+
+    dynamics._model_sharded_sum = counted
+
+    def drained(solver, from_thread=False):
+        eng = Engine(jax.random.PRNGKey(0))
+        adapter = eng.install("mem", solver.as_engine_solver())
+        futs = [eng.submit(Request("mem", p)) for p in payloads]
+        if from_thread:
+            seen = {}
+            def work():
+                seen["plan"] = shard_lib.current_plan()
+                eng.drain()
+            t = threading.Thread(target=work)
+            t.start()
+            t.join()
+            assert seen["plan"] is None
+        else:
+            eng.drain()
+        return [f.result() for f in futs], adapter, eng
+
+    unplanned, _, _ = drained(plain)
+    attrs_unplanned = list(attrs)
+    traced = [collective_traces[0]]
+    got, adapter, eng = drained(planned)
+    attrs_planned = attrs[len(attrs_unplanned):]
+    traced.append(collective_traces[0])
+    retrieves = dynamics.TRACE_COUNTER["retrieve"]
+    threaded, thread_adapter, _ = drained(planned, from_thread=True)
+    thread_retraced = dynamics.TRACE_COUNTER["retrieve"] - retrieves
+
+    oracle_cfg = api.ONNConfig(n=N, backend="parallel", max_cycles=40)
+    oracle_params = api.make_params(oracle_cfg, w)
+    oracle = []
+    for p in payloads:
+        lanes = [dynamics.run(oracle_cfg, oracle_params, dynamics.initial_phase(oracle_cfg, jnp.asarray(s)))
+                 for s in np.atleast_2d(p)]
+        r = jax.tree.map(lambda *xs: np.stack([np.asarray(x) for x in xs]), *lanes)
+        oracle.append(jax.tree.map(lambda x: x[0], r) if p.ndim == 1 else r)
+
+    solve_exact = same(planned.solve(jnp.asarray(payloads[1])), plain.solve(jnp.asarray(payloads[1])))
+
+    ceng = ContinuousEngine(jax.random.PRNGKey(0), slab_lanes=16)
+    cadapter = ceng.install("mem", planned.as_engine_solver())
+    cfuts = [ceng.submit(Request("mem", p)) for p in payloads]
+    ceng.flush()
+    continuous = [f.result() for f in cfuts]
+    traced.append(collective_traces[0])
+
+    xi2, w2 = library(1)
+    eng.hot_swap("mem", api.make_params(cfg, w2))
+    _, params_b = adapter._padded_instance(N)
+    futs = [eng.submit(Request("mem", p)) for p in payloads]
+    eng.drain()
+    swapped = [f.result() for f in futs]
+    plain2 = api.RetrievalSolver(config=cfg, params=api.make_params(cfg, w2))
+    expect2, _, _ = drained(plain2)
+
+    # The daemon's workloads: both retrieval solvers hold the plan.
+    from repro import serving
+
+    def served_mixed(p):
+        ce = ContinuousEngine(jax.random.PRNGKey(0), slab_lanes=16)
+        serving.install_mixed_workloads(ce, plan=p)
+        reqs = [r for r in serving.mixed_requests(8, seed=0) if r.workload != "cuts"]
+        futs = [ce.submit(r) for r in reqs]
+        ce.flush()
+        return [f.result() for f in futs], ce
+
+    mixed_plain, _ = served_mixed(None)
+    mixed_planned, mixed_eng = served_mixed(plan)
+    mixed_adapters = [mixed_eng.solver(name) for name in ("small", "large")]
+
+    print(json.dumps({
+        "mixed_exact": all_same(mixed_planned, mixed_plain),
+        "mixed_held": [a._plan == plan for a in mixed_adapters],
+        "mixed_sharded_slabs": [a.stats()["sharded_slabs"] for a in mixed_adapters],
+        "mixed_row_sharded": [sorted((nb, row_sharded(pb)) for nb, (_, pb) in a._padded.items())
+                              for a in mixed_adapters],
+        "engine_exact": all_same(got, unplanned),
+        "oracle_exact": all_same(got, oracle),
+        "solve_exact": solve_exact,
+        "thread_exact": all_same(threaded, got),
+        "continuous_exact": all_same(continuous, got),
+        "shards": shard_bytes(adapter.solver.params),
+        "collective_traces": traced,
+        "thread_retraced": thread_retraced,
+        "sharded_slabs": [adapter.stats()["sharded_slabs"], thread_adapter.stats()["sharded_slabs"],
+                          cadapter.stats()["sharded_slabs"]],
+        "unplanned_attrs": [sorted(a) for a in attrs_unplanned],
+        "planned_attrs": [{k: a[k] for k in ("plan", "devices", "w_bytes_per_device",
+                                             "combine_bytes_per_cycle", "width")}
+                          for a in attrs_planned],
+        "swap_row_sharded": [row_sharded(adapter.solver.params), row_sharded(params_b)],
+        "swap_shards": shard_bytes(params_b),
+        "swap_exact": all_same(swapped, expect2),
+    }))
+    """
+)
+
+_N_PLANNED = 512
+
+
+@pytest.fixture(scope="module")
+def planned_engine():
+    return _run_subprocess(_PLANNED_ENGINE_SCRIPT, timeout=600)
+
+
+def test_planned_engine_slab_matches_unplanned_and_oracle(planned_engine):
+    """A drained slab mixing 1- and 8-lane requests through a solver that
+    holds ShardPlan(1x4) is bit-identical to the unplanned engine and to the
+    per-lane ``dynamics.run`` oracle; ``RetrievalSolver.solve`` under its own
+    plan matches the unplanned solve."""
+    assert planned_engine["engine_exact"]
+    assert planned_engine["oracle_exact"]
+    assert planned_engine["solve_exact"]
+
+
+def test_planned_solver_places_w_in_four_row_shards(planned_engine):
+    devices, per_device = planned_engine["shards"]
+    assert devices == 4
+    assert per_device == [_N_PLANNED * _N_PLANNED // 4] * 4
+
+
+def test_planned_drain_from_another_thread_and_continuous_slab(planned_engine):
+    """No ambient context is needed: a drain on a fresh thread and a
+    ContinuousEngine slab solve sharded, with the same results."""
+    assert planned_engine["thread_exact"]
+    assert planned_engine["continuous_exact"]
+    assert all(c >= 1 for c in planned_engine["sharded_slabs"])
+    # The unplanned solve never traced the collective; the planned drain
+    # did; the thread's drain reused that executable (the same plan
+    # context in its cache key); the continuous slab traced it again.
+    unplanned, planned, continuous = planned_engine["collective_traces"]
+    assert unplanned == 0 and planned > 0 and continuous > planned
+    assert planned_engine["thread_retraced"] == 0
+
+
+def test_planned_slab_span_names_the_plan(planned_engine):
+    """``engine.slab`` carries the plan's attributes on sharded slabs only."""
+    assert all("plan" not in a for a in planned_engine["unplanned_attrs"])
+    attrs = planned_engine["planned_attrs"]
+    assert attrs and all(
+        a["plan"] == "1x4" and a["devices"] == 4
+        and a["w_bytes_per_device"] == _N_PLANNED * _N_PLANNED // 4
+        and a["combine_bytes_per_cycle"] == a["width"] * _N_PLANNED * 4
+        for a in attrs
+    )
+
+
+def test_planned_hot_swap_keeps_row_sharding(planned_engine):
+    assert planned_engine["swap_row_sharded"] == [True, True]
+    devices, per_device = planned_engine["swap_shards"]
+    assert devices == 4 and per_device == [_N_PLANNED * _N_PLANNED // 4] * 4
+    assert planned_engine["swap_exact"]
+
+
+def test_daemon_retrieval_workloads_hold_the_plan(planned_engine):
+    """``serving.install_mixed_workloads(plan=)``, the daemon's installer,
+    gives both retrieval solvers the plan: their padded couplings sit in row
+    shards, their slabs solve sharded, and the results match the unplanned
+    workloads bit for bit."""
+    assert planned_engine["mixed_held"] == [True, True]
+    for buckets in planned_engine["mixed_row_sharded"]:  # each N bucket a slab used
+        assert buckets and all(nb % 4 == 0 and placed for nb, placed in buckets)
+    assert all(c >= 1 for c in planned_engine["mixed_sharded_slabs"])
+    assert planned_engine["mixed_exact"]
