@@ -7,8 +7,10 @@ resolves the tuner's block choice for **every** ``(kind, N, batch)`` bucket
 (:func:`repro.kernels.autotune.iter_buckets`), evaluates the per-grid-step
 working set of each kernel that runs with those blocks — the same BlockSpec
 accounting the kernels use, extended with the bias/phase/scratch operands
-the tuner's quick estimate omits — and compares against the committed
-budgets (``VMEM_BUDGET_BYTES`` / ``MULTI_VMEM_BUDGET_BYTES``).
+the hybrid and matvec tuners' quick estimate omits; for the streaming
+"step" kernels, two pipeline buffers of every blocked operand — and
+compares against the committed budgets (``STEP_VMEM_BUDGET_BYTES`` /
+``VMEM_BUDGET_BYTES`` / ``MULTI_VMEM_BUDGET_BYTES``).
 
 No kernel is compiled and no array is built; the check is pure integer
 arithmetic over the tuner's outputs, so it runs in CI in milliseconds via
@@ -54,20 +56,6 @@ def _pad128(n: int) -> int:
     return -(-n // 128) * 128
 
 
-def _step_working_sets(bb: int, bi: int, bk: int) -> Dict[str, int]:
-    """Per-grid-step bytes of every kernel launched with "step" blocks."""
-    sig = bb * bk  # int8 spins
-    w = bi * bk  # int8 weight tile
-    acc = bb * bi * 4  # int32 accumulator scratch
-    bias = bi * 4
-    return {
-        "coupling_sum": sig + w + acc,
-        "onn_step": sig + w + bias + bb * bi + bb * bi + acc,  # σ_self + int8 out
-        "phase_step": sig + w + bias + 3 * (bb * bi * 4),  # θ in, θ out, acc
-        "phase_step_packed": _k.packed_phase_vmem_bytes(bb, bi, bk) + bias,
-    }
-
-
 def _hybrid_working_sets(bb: int, bi: int, bk: int, n: int) -> Dict[str, int]:
     """Serialized-MAC launches: the MAC pass and the fused epilogue.
 
@@ -108,8 +96,9 @@ def check_bucket(kind: str, n: int, batch: int) -> BucketReport:
         sets = _matvec_working_sets(bb, bi, bk)
         budget = autotune.VMEM_BUDGET_BYTES
     else:
-        sets = _step_working_sets(bb, bi, bk)
-        budget = autotune.VMEM_BUDGET_BYTES
+        # both pipeline buffers of each blocked operand, as the tuner counts
+        sets = _k.step_vmem_bytes(bb, bi, bk)
+        budget = autotune.STEP_VMEM_BUDGET_BYTES
     kernel = max(sets, key=sets.__getitem__)
     return BucketReport(
         kind=kind, n=n, batch=batch, blocks=tuple(blocks),

@@ -7,16 +7,23 @@ set of ``(N, batch)`` buckets, so the right tiles can be *picked once per
 bucket* — at engine install time — and reused for the lifetime of the jit
 executable.
 
-The tuner is analytic, not search-based: on this CPU-only container the
-kernels run in interpret mode, so measured autotuning would tune the
-interpreter.  The model maximizes tile size (fewer grid steps, higher MXU
-occupancy, fewer HBM round-trips per operand byte) subject to
+The tuner is analytic, not search-based: no kernel is timed at run time.
+The model maximizes tile size (fewer grid steps, higher MXU occupancy, fewer
+HBM round-trips per operand byte) subject to
 
 * hardware alignment — power-of-two tiles, shrunk toward the operand extent
   so padding waste stays bounded (``_pick_block`` semantics), and
-* the VMEM budget — the working set of one grid step
-  (:func:`repro.kernels.coupling_kernel.vmem_bytes`) must fit well inside
-  the ~16 MiB/core VMEM, leaving headroom for double buffering.
+* a VMEM budget per kind.  The ``"step"`` tiles of the streaming kernels grow
+  until the working set of one grid step, counted as the pipeline holds it
+  with two buffers of every blocked operand
+  (:func:`repro.kernels.coupling_kernel.step_vmem_bytes`), would pass
+  :data:`STEP_VMEM_BUDGET_BYTES`: on a v5e each grid step costs about
+  0.36 µs whatever it moves, so a (32768 × 131072) W block streams at its
+  HBM roofline only in wide tiles.  ``"hybrid"`` and ``"matvec"`` shrink
+  from fixed preferences until one buffer of each operand
+  (:func:`repro.kernels.coupling_kernel.vmem_bytes`) fits
+  :data:`VMEM_BUDGET_BYTES`; ``"multi"`` sizes its resident W against
+  :data:`MULTI_VMEM_BUDGET_BYTES`.
 
 Results are cached on the bucket key, so repeated engine installs (and the
 jit retrace they must *not* cause) resolve to identical static block tuples;
@@ -31,16 +38,20 @@ from typing import Dict, Iterator, NamedTuple, Tuple
 
 from repro.kernels import coupling_kernel as _k
 
-#: Per-grid-step VMEM budget: a quarter of the ~16 MiB/core VMEM, leaving
-#: room for Pallas' double-buffered pipeline (in-flight next tiles) and the
-#: output block.
+#: Budget of the ``"hybrid"`` and ``"matvec"`` tiles, one buffer of each
+#: operand counted (:func:`repro.kernels.coupling_kernel.vmem_bytes`).
 VMEM_BUDGET_BYTES = (16 * 2**20) // 4
+
+#: Budget of one ``"step"`` grid step with both pipeline buffers of every
+#: blocked operand counted (:func:`repro.kernels.coupling_kernel.step_vmem_bytes`):
+#: the v5e's 16 MiB scoped VMEM less 2 MiB for the values the kernel body
+#: keeps live (the dot's partial sums, the epilogue's selects).
+STEP_VMEM_BUDGET_BYTES = 14 * 2**20
 
 #: Budget for the multi-cycle kernel's pipelined blocks
 #: (:func:`repro.kernels.coupling_kernel.multi_vmem_bytes`): the 16 MiB
 #: scoped-VMEM limit of a v5e kernel, less 2 MiB for the values the
-#: in-kernel cycle loop keeps live.  The kernel runs one grid step per
-#: batch block, so its blocks may fill the limit instead of a quarter of it.
+#: in-kernel cycle loop keeps live.
 MULTI_VMEM_BUDGET_BYTES = 14 * 2**20
 
 #: Largest padded N whose resident (N, N) int8 weight tile fits the
@@ -108,6 +119,34 @@ def _shrink_to_budget(bb: int, bi: int, bk: int, minimum: int = 8) -> BlockConfi
     return BlockConfig(bb, bi, bk)
 
 
+def _step_tiles(bb: int, m: int, n: int) -> BlockConfig:
+    """The streaming kernels' tiles: as few grid steps as VMEM allows.
+
+    From the lane tile (or the whole extent, below 128), double the narrower
+    of ``block_i`` and ``block_k`` — ``block_k`` on a tie — while the doubled
+    tile still divides its lane-padded extent (so W is never padded further,
+    and a large W is never copied) and the double-buffered working set fits
+    :data:`STEP_VMEM_BUDGET_BYTES`.
+    """
+    bi, bk = _lane_tile(m, 128), _lane_tile(n, 128)
+
+    def can_double(tile: int, size: int) -> bool:
+        return size >= 128 and (-(-size // 128) * 128) % (2 * tile) == 0
+
+    def fits(bi: int, bk: int) -> bool:
+        return max(_k.step_vmem_bytes(bb, bi, bk).values()) <= STEP_VMEM_BUDGET_BYTES
+
+    while True:
+        grow_k = can_double(bk, n) and fits(bi, 2 * bk)
+        grow_i = can_double(bi, m) and fits(2 * bi, bk)
+        if grow_k and (bk <= bi or not grow_i):
+            bk *= 2
+        elif grow_i:
+            bi *= 2
+        else:
+            return BlockConfig(bb, bi, bk)
+
+
 def blocks_for(kind: str, *, n: int, batch: int, m: int | None = None) -> BlockConfig:
     """The tuned ``(block_b, block_i, block_k)`` for one ``(N, batch)`` bucket.
 
@@ -143,14 +182,15 @@ def blocks_for(kind: str, *, n: int, batch: int, m: int | None = None) -> BlockC
         bm = _lane_tile(m, _k.DEFAULT_BLOCK_I)
         bk = _pick(n, 512, minimum=128)
         cfg = _shrink_to_budget(bb, bm, bk, minimum=8)
-    else:
-        # "step" / "hybrid": int8 MAC tiles.  Wider-than-default contraction
-        # and row tiles pay off once the operand extent supports them (fewer
-        # grid steps over the same bytes); small buckets shrink toward their
-        # extent as before.
+    elif kind == "hybrid":
+        # block_k is the target width of a serialized MAC pass-group
+        # (``hybrid_pass_groups``), a parameter of the FPGA cycle model, so
+        # these tiles keep their fixed preferences.
         bi = _lane_tile(m, 256 if m >= 256 else 128)
         bk = _lane_tile(n, 256 if n >= 256 else 128)
         cfg = _shrink_to_budget(bb, bi, bk)
+    else:
+        cfg = _step_tiles(bb, m, n)
     _CACHE[key] = cfg
     return cfg
 
@@ -188,12 +228,26 @@ def iter_buckets(
                 yield kind, n, batch
 
 
-def cache_info() -> Dict[str, int]:
-    """Tuner cache summary for ``stats()`` surfaces."""
+def grid_steps(kind: str, m: int, n: int, batch: int, cfg: BlockConfig) -> int:
+    """Grid steps the tiles cut a ``(kind, m, n, batch)`` operand set into
+    (for ``"hybrid"``, summed over its pass-group launches at P ≤ block_k)."""
+    steps = -(-batch // cfg.block_b)
+    if kind != "multi":
+        steps *= -(-m // cfg.block_i) * -(-n // cfg.block_k)
+    return steps
+
+
+def cache_info() -> Dict[str, object]:
+    """Tuner cache summary for ``stats()`` surfaces: entries, hits, misses,
+    and per kind the largest grid-step count among the resolved entries."""
+    most: Dict[str, int] = {}
+    for (kind, m, n, batch), cfg in _CACHE.items():
+        most[kind] = max(most.get(kind, 0), grid_steps(kind, m, n, batch, cfg))
     return {
         "entries": len(_CACHE),
         "hits": int(TUNE_COUNTER["hit"]),
         "misses": int(TUNE_COUNTER["miss"]),
+        "max_grid_steps": most,
     }
 
 

@@ -76,6 +76,29 @@ def vmem_bytes(bb: int, bi: int, bk: int, fused: bool = True) -> int:
     return sig + w + acc + sig_self + out
 
 
+def step_vmem_bytes(bb: int, bi: int, bk: int) -> dict:
+    """Scoped VMEM of one grid step of each kernel launched with "step" tiles.
+
+    The pipeline keeps two buffers of every blocked input and of the output
+    block (the next tile's DMA lands while this one is used), and one of the
+    int32 accumulator scratch.  The packed kernel runs on the square block
+    ``ops.phase_step_packed`` derives from the pair.
+    """
+    sig = bb * bk  # int8 spins
+    w = bi * bk  # int8 weight tile
+    bias = bi * 4
+    tile32 = bb * bi * 4  # one int32 (bb, bi) block
+    blk = -(-max(bi, bk) // PACKED_BLOCK_MULTIPLE) * PACKED_BLOCK_MULTIPLE
+    return {
+        "coupling_sum": 2 * (sig + w + tile32),  # accumulates in its output block
+        "onn_step": 2 * (sig + w + bias + 2 * bb * bi) + tile32,  # σ_self, int8 out
+        "phase_step": 2 * (sig + w + bias + 2 * tile32) + tile32,  # θ in, θ out
+        # packed σ and keep-θ views (two counters a byte), W, bias, θ out
+        "phase_step_packed": 2 * (bb * blk + blk * blk + blk * 4 + bb * blk * 4)
+        + bb * blk * 4,
+    }
+
+
 # ---------------------------------------------------------------------------
 # coupling_sum: S = σ @ Wᵀ, int32 accumulation in the output block.
 # ---------------------------------------------------------------------------
@@ -335,16 +358,6 @@ def _pack_nibbles(vals: jax.Array) -> jax.Array:
     """(bb, w) int32 counters in [0, 16) → (bb, w/2) block-halves uint8."""
     h = vals.shape[1] // 2
     return (vals[:, :h] | (vals[:, h:] << 4)).astype(jnp.uint8)
-
-
-def packed_phase_vmem_bytes(bb: int, bi: int, bk: int) -> int:
-    """VMEM working set of one ``phase_step_packed_pallas`` grid step."""
-    packed_sig = bb * (bk // 2)  # uint8, two θ per byte
-    w = bi * bk  # int8
-    acc = bb * bi * 4  # int32 accumulator
-    packed_keep = bb * (bi // 2)  # uint8 keep-θ view
-    out = bb * bi * 4  # int32 phases out
-    return packed_sig + w + acc + packed_keep + out
 
 
 def _phase_step_packed_kernel(

@@ -209,14 +209,75 @@ def test_autotune_blocks_deterministic_and_within_budget():
                 a = autotune.blocks_for(kind, n=n, batch=batch)
                 b = autotune.blocks_for(kind, n=n, batch=batch)
                 assert a == b
-                assert ck.vmem_bytes(a.block_b, a.block_i, a.block_k, fused=True) \
-                    <= autotune.VMEM_BUDGET_BYTES
+                if kind == "step":
+                    assert max(ck.step_vmem_bytes(*a).values()) \
+                        <= autotune.STEP_VMEM_BUDGET_BYTES
+                else:
+                    assert ck.vmem_bytes(a.block_b, a.block_i, a.block_k, fused=True) \
+                        <= autotune.VMEM_BUDGET_BYTES
+
+
+#: Per-chip row block of the N=131072 library row-sharded over four chips.
+SHARDED_BLOCK = dict(n=131072, batch=128, m=32768)
+
+
+def test_autotune_step_tiles_divide_sharded_block():
+    """The streaming tiles divide the (32768 × 131072) W block — so it is
+    never padded, i.e. never copied — in at most 4,096 grid steps."""
+    cfg = autotune.blocks_for("step", **SHARDED_BLOCK)
+    assert SHARDED_BLOCK["m"] % cfg.block_i == 0
+    assert SHARDED_BLOCK["n"] % cfg.block_k == 0
+    assert SHARDED_BLOCK["batch"] % cfg.block_b == 0
+    assert autotune.grid_steps("step", 32768, 131072, 128, cfg) <= 4096
+
+
+def test_autotune_step_buckets_fit_double_buffered_budget():
+    from repro.kernels import coupling_kernel as ck
+
+    shapes = [dict(n=n, batch=b) for _, n, b in autotune.iter_buckets(("step",))]
+    assert shapes
+    for shape in shapes + [SHARDED_BLOCK, dict(n=1024, batch=256, m=64)]:
+        cfg = autotune.blocks_for("step", **shape)
+        sets = ck.step_vmem_bytes(*cfg)
+        assert set(sets) == {"coupling_sum", "onn_step", "phase_step", "phase_step_packed"}
+        assert max(sets.values()) <= autotune.STEP_VMEM_BUDGET_BYTES, (shape, cfg)
+
+
+#: "hybrid" tiles as the tuner gave them before the streaming rule: block_b
+#: by batch bucket, block_i == block_k by N bucket.
+_HYBRID_BB = {1: 8, 2: 8, 4: 8, 8: 8, 16: 16, 32: 32, 64: 64, 128: 128, 256: 128}
+_HYBRID_TILE = {16: 16, 32: 32, 48: 48, 64: 64, 128: 128, 256: 256, 506: 256,
+                512: 256, 1024: 256, 2048: 256, 4096: 256}
+
+
+@pytest.mark.parametrize("n", sorted(_HYBRID_TILE))
+def test_autotune_hybrid_tiles_pinned(n):
+    assert set(_HYBRID_TILE) == set(autotune.N_BUCKETS)
+    assert set(_HYBRID_BB) == set(autotune.BATCH_BUCKETS)
+    for batch, bb in _HYBRID_BB.items():
+        got = autotune.blocks_for("hybrid", n=n, batch=batch)
+        assert tuple(got) == (bb, _HYBRID_TILE[n], _HYBRID_TILE[n]), (n, batch)
+
+
+def test_cache_info_reports_max_grid_steps():
+    autotune.clear_cache()
+    try:
+        autotune.warm(n=48, batch=16)
+        small = autotune.cache_info()["max_grid_steps"]
+        assert set(small) == {"step", "hybrid", "multi"}
+        assert small["step"] == 1 and small["multi"] == 1
+        cfg = autotune.blocks_for("step", **SHARDED_BLOCK)
+        steps = autotune.cache_info()["max_grid_steps"]["step"]
+        assert steps == (32768 // cfg.block_i) * (131072 // cfg.block_k)
+        assert steps <= 4096
+    finally:
+        autotune.clear_cache()
 
 
 def test_autotune_cache_hits_and_warm_idempotent():
     autotune.clear_cache()
     info0 = autotune.cache_info()
-    assert info0 == {"entries": 0, "hits": 0, "misses": 0}
+    assert info0 == {"entries": 0, "hits": 0, "misses": 0, "max_grid_steps": {}}
     autotune.warm(n=48, batch=16)
     after_first = autotune.cache_info()
     assert after_first["entries"] == after_first["misses"] == 3

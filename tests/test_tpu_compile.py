@@ -80,16 +80,20 @@ def _hybrid_sum(n, b, parallel):
     return fn, [((b, n), jnp.int8), ((n, n), jnp.int8)]
 
 
-def _ising_slab(n, replicas, groups):
-    # One staggered update group: (window, N) coupling rows against the
-    # (replicas, N) spins — the Max-Cut path's field evaluation.
-    m = -(-n // groups)
-    bb, bi, bk = autotune.blocks_for("step", n=n, batch=replicas, m=m)
-    m_pad, r_pad = -(-m // bi) * bi, -(-replicas // bb) * bb
+def _coupling_sum(m, n, b):
+    # The row-slab kernel: (M, N) coupling rows against (b, N) spins.
+    bb, bi, bk = autotune.blocks_for("step", n=n, batch=b, m=m)
+    m_pad, b_pad = -(-m // bi) * bi, -(-b // bb) * bb
     fn = functools.partial(
         k.coupling_sum_pallas, block_b=bb, block_i=bi, block_k=bk, interpret=False
     )
-    return fn, [((r_pad, n), jnp.int8), ((m_pad, n), jnp.int8)]
+    return fn, [((b_pad, n), jnp.int8), ((m_pad, n), jnp.int8)]
+
+
+def _ising_slab(n, replicas, groups):
+    # One staggered update group: (window, N) coupling rows against the
+    # (replicas, N) spins — the Max-Cut path's field evaluation.
+    return _coupling_sum(-(-n // groups), n, replicas)
 
 
 CASES = {
@@ -100,6 +104,10 @@ CASES = {
     "phase_step_packed_n2048_b128": lambda: _phase_step_packed(2048, 128),
     "hybrid_coupling_sum_n512_p32": lambda: _hybrid_sum(512, 128, parallel=32),
     "ising_slab_n512_r8": lambda: _ising_slab(512, replicas=8, groups=16),
+    # G1 Max-Cut: 16 groups of 64 rows of the 1024 bucket, 32 graphs x 8 replicas.
+    "ising_slab_n1024_r256": lambda: _ising_slab(1024, replicas=256, groups=16),
+    # One chip's row block of the N=131072 library sharded over four chips.
+    "coupling_sum_n131072_m32768_b128": lambda: _coupling_sum(32768, 131072, 128),
 }
 
 
