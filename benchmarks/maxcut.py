@@ -34,12 +34,10 @@ from repro.core import dynamics
 from repro.core import ising
 
 SIZES = (48, 128, 506)
-#: (backend, parallel_factor, hybrid_impl) sweep; the pallas pass-group
-#: route is asserted bit-exact in tests/test_ising.py and interp-mode cost
-#: keeps it out of the timed CI sweep at large N.
+#: (backend, parallel_factor) sweep.
 BACKENDS = (
-    ("parallel", 0, "scan"),
-    ("hybrid", 32, "scan"),
+    ("parallel", 0),
+    ("hybrid", 32),
 )
 STAGGER_GROUPS = 16
 
@@ -53,10 +51,9 @@ def _legacy_replicas(adj: jax.Array, keys: jax.Array, sweeps: int, weight_bits: 
     )(keys)
 
 
-def _cfg(n: int, backend: str, p: int, impl: str, sweeps: int) -> dynamics.ONNConfig:
+def _cfg(n: int, backend: str, p: int, sweeps: int) -> dynamics.ONNConfig:
     return dynamics.ONNConfig(
-        n=n, backend=backend, parallel_factor=p, hybrid_impl=impl,
-        max_cycles=sweeps, settle_chunk=0,
+        n=n, backend=backend, parallel_factor=p, max_cycles=sweeps, settle_chunk=0
     )
 
 
@@ -78,7 +75,7 @@ def bench_size(n: int, replicas: int, sweeps: int, trials: int) -> List[Dict[str
             cfg, adj, solve_key, replicas=replicas, stagger_groups=STAGGER_GROUPS
         )
 
-    ref_cfg = _cfg(n, "parallel", 0, "scan", sweeps)
+    ref_cfg = _cfg(n, "parallel", 0, sweeps)
     ref = solve(ref_cfg)
     legacy_keys = jax.random.split(solve_key, replicas)
     legacy = _legacy_replicas(adj, legacy_keys, sweeps, ref_cfg.weight_bits)
@@ -89,8 +86,8 @@ def bench_size(n: int, replicas: int, sweeps: int, trials: int) -> List[Dict[str
     )
 
     rows = []
-    for backend, p, impl in BACKENDS:
-        cfg = _cfg(n, backend, p, impl, sweeps)
+    for backend, p in BACKENDS:
+        cfg = _cfg(n, backend, p, sweeps)
         # Bit-exactness gate: every backend row must replay the parallel
         # reference exactly before its timing means anything.
         res = ref if cfg == ref_cfg else solve(cfg)
@@ -98,11 +95,11 @@ def bench_size(n: int, replicas: int, sweeps: int, trials: int) -> List[Dict[str
             got, want = np.asarray(getattr(res, field)), np.asarray(getattr(ref, field))
             if not np.array_equal(got, want):
                 raise AssertionError(
-                    f"maxcut backend {backend}/{impl} P={p} diverged from parallel "
+                    f"maxcut backend {backend} P={p} diverged from parallel "
                     f"at N={n}, field {field!r}"
                 )
         solve_s = calibration.time_best(lambda: solve(cfg).cut_value, trials)
-        label = backend if backend != "hybrid" else f"hybrid[{impl},P={p}]"
+        label = backend if backend != "hybrid" else f"hybrid[P={p}]"
         rows.append({
             "n": n,
             "backend": label,

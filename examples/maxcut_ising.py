@@ -32,7 +32,7 @@ def main():
     ap.add_argument("--stagnation", type=int, default=12,
                     help="sweeps without improvement before a replica stops")
     ap.add_argument("--backend", default="parallel",
-                    choices=["parallel", "serial", "pallas", "hybrid"])
+                    choices=["parallel", "pallas", "hybrid"])
     ap.add_argument("--parallel-factor", type=int, default=0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()

@@ -21,7 +21,7 @@ def main():
     ap.add_argument("--requests", type=int, default=512)
     ap.add_argument("--corruption", type=float, default=0.25)
     ap.add_argument("--backend", default="parallel",
-                    choices=["parallel", "serial", "pallas"])
+                    choices=["parallel", "pallas"])
     args = ap.parse_args()
 
     print("dataset,arch,requests,accuracy,settle_cycles,req_per_s")

@@ -29,7 +29,7 @@ def main():
     ap.add_argument("--probes", type=int, default=24)
     ap.add_argument("--no-qat", action="store_true")
     ap.add_argument("--backend", default="parallel",
-                    choices=("parallel", "serial", "pallas", "hybrid"))
+                    choices=("parallel", "pallas", "hybrid"))
     args = ap.parse_args()
 
     datasets = [args.dataset] if args.dataset else ["5x4", "7x6", "10x10"]

@@ -7,7 +7,7 @@ resolves the tuner's block choice for **every** ``(kind, N, batch)`` bucket
 (:func:`repro.kernels.autotune.iter_buckets`), evaluates the per-grid-step
 working set of each kernel that runs with those blocks — the same BlockSpec
 accounting the kernels use, extended with the bias/phase/scratch operands
-the hybrid and matvec tuners' quick estimate omits; for the streaming
+the matvec tuner's quick estimate omits; for the streaming
 "step" kernels, two pipeline buffers of every blocked operand — and
 compares against the committed budgets (``STEP_VMEM_BUDGET_BYTES`` /
 ``VMEM_BUDGET_BYTES`` / ``MULTI_VMEM_BUDGET_BYTES``).
@@ -56,21 +56,6 @@ def _pad128(n: int) -> int:
     return -(-n // 128) * 128
 
 
-def _hybrid_working_sets(bb: int, bi: int, bk: int, n: int) -> Dict[str, int]:
-    """Serialized-MAC launches: the MAC pass and the fused epilogue.
-
-    A pass-group's contraction width is ``hybrid_pass_groups(P, bk)[1]``;
-    the widest case over every legal P is ``max(bk, N_padded)`` (P = N runs
-    the whole contraction in one pass).  The int32 accumulator is donated
-    via ``input_output_aliases`` so it is counted once.
-    """
-    width = max(bk, _pad128(n))
-    acc = bb * bi * 4
-    mac = bb * width + bi * width + acc
-    epilogue = acc + bi * 4 + bb * bi * 4 + bb * bi * 4  # + bias, θ in, θ out
-    return {"hybrid_mac_pass": mac, "hybrid_phase_epilogue": epilogue}
-
-
 def _matvec_working_sets(bb: int, bm: int, bk: int) -> Dict[str, int]:
     x = bb * bk * 4  # f32 activations
     w = bm * bk  # int8 weight tile
@@ -89,9 +74,6 @@ def check_bucket(kind: str, n: int, batch: int) -> BucketReport:
             "phase_step_multi": _k.multi_vmem_bytes(bb, _pad128(n), packed=False)
         }
         budget = autotune.MULTI_VMEM_BUDGET_BYTES
-    elif kind == "hybrid":
-        sets = _hybrid_working_sets(bb, bi, bk, n)
-        budget = autotune.VMEM_BUDGET_BYTES
     elif kind == "matvec":
         sets = _matvec_working_sets(bb, bi, bk)
         budget = autotune.VMEM_BUDGET_BYTES

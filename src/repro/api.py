@@ -8,11 +8,12 @@ Everything a caller needs rides on three ideas:
   (config, shape) and compose with ``jax.vmap`` over params (many problem
   instances, one executable), sharding, and donation.
 
-* **One backend table.**  ``ONNConfig.backend`` ∈ {"parallel", "serial",
-  "pallas", "hybrid"} picks the weighted-sum schedule for *both* functional
-  and rtl modes; all are bit-exact.  ``hybrid`` is the cycle-faithful
-  serialized-MAC datapath of the paper's headline architecture
-  (``parallel_factor`` sets the MAC width P; ceil(N/P) passes per cycle).
+* **One backend table.**  ``ONNConfig.backend`` ∈ {"parallel", "pallas",
+  "hybrid"} picks the weighted-sum schedule for *both* functional and rtl
+  modes; all are bit-exact.  ``hybrid`` is the paper's serialized-MAC
+  schedule as an executable model (``parallel_factor`` sets the MAC width
+  P; ceil(N/P) passes per cycle): it is chosen for its FPGA quotes and is
+  never faster on the chip.
 
 * **One solver surface.**  A ``Solver`` maps a problem instance to a result
   under an explicit PRNG key.  ``RetrievalSolver`` (batched associative
@@ -140,10 +141,9 @@ class MaxCutSolver:
     ``sweeps`` grouped-staggered sweeps (``stagger_groups`` update groups
     per sweep; 0 → auto, N → fully asynchronous), with every field
     evaluation dispatched through the same ``backend`` table as retrieval —
-    ``"hybrid"`` with ``parallel_factor`` runs the serialized-MAC datapath,
-    ``hybrid_impl="pallas"`` the fused pass-group kernels.  ``stagnation``
-    > 0 freezes a replica after that many sweeps without a better cut
-    (early exit, checked every ``settle_chunk`` sweeps).
+    ``"hybrid"`` with ``parallel_factor`` runs the serialized-MAC datapath.
+    ``stagnation`` > 0 freezes a replica after that many sweeps without a
+    better cut (early exit, checked every ``settle_chunk`` sweeps).
     """
 
     sweeps: int = 64
@@ -153,7 +153,6 @@ class MaxCutSolver:
     stagnation: int = 0  # sweeps without improvement before freeze (0 = off)
     backend: str = "parallel"
     parallel_factor: int = 0
-    hybrid_impl: str = "scan"
     settle_chunk: int = 8
 
     def config(self, n: int) -> ONNConfig:
@@ -164,7 +163,6 @@ class MaxCutSolver:
             max_cycles=self.sweeps,
             backend=self.backend,
             parallel_factor=self.parallel_factor,
-            hybrid_impl=self.hybrid_impl,
             settle_chunk=self.settle_chunk,
         )
 

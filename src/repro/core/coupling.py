@@ -53,7 +53,9 @@ def weighted_sum_serial(w: jax.Array, sigma: jax.Array, chunk: int = 1) -> jax.A
 
     Accumulates over inputs ``chunk`` at a time with a ``lax.scan`` — the
     executable model of the fast-clock counter + single MAC (``chunk=1``) or
-    of the blocked VMEM streaming schedule of the TPU kernel (``chunk>1``).
+    of P parallel MAC lanes (``chunk=P``, ``ceil(N / P)`` passes).  It is
+    the ``hybrid`` backend of ``repro.core.dynamics``, with
+    ``chunk = ONNConfig.hybrid_parallel``.
     Bit-exact to :func:`weighted_sum_parallel` by integer associativity; when
     ``chunk`` does not divide N the contraction dimension is zero-padded (the
     hardware analogue: the MAC idles on the tail fast-clock edges), which

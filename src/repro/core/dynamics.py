@@ -27,21 +27,17 @@ Weighted-sum backends (``ONNConfig.backend``), one dispatch table shared by
 both modes:
 
 * ``parallel`` — fully parallel einsum (the recurrent adder tree, Fig. 4).
-* ``serial``   — chunked ``lax.scan`` accumulation (the hybrid serialized
-  MAC, Fig. 5; ``serial_chunk`` sets the block size, any N).
 * ``pallas``   — the blocked TPU kernel (``repro.kernels``), interpret mode
   on CPU.  In functional mode the full cycle is one fused kernel launch
   (int8 matmul + bias + phase-align epilogue over the real batch grid).
 * ``hybrid``   — the cycle-faithful emulation of the paper's hybrid
   coupling datapath: the N×N coupling is serialized into
   ``ceil(N / parallel_factor)`` passes of ``parallel_factor``-wide integer
-  MACs over int8-carried weights (``hybrid_mac_sum``).  ``parallel_factor``
-  (P) is the architecture's parallelism knob: P=1 is the paper's single-MAC
-  hybrid, P=N degenerates to the recurrent parallel schedule.
-  ``hybrid_impl`` selects the execution route: ``"scan"`` (the
-  ``lax.scan`` reference below) or ``"pallas"`` (the blocked pass-group
-  kernels in ``repro.kernels`` — one launch per pass-group, real batch
-  grid).
+  MACs over int8-carried weights (``coupling.weighted_sum_serial``).
+  ``parallel_factor`` (P) is the architecture's parallelism knob: P=1 is
+  the paper's single-MAC hybrid, P=N degenerates to the recurrent parallel
+  schedule.  It is the executable model of the FPGA schedule, chosen for
+  its hardware quotes; it is never faster on a TPU.
 
 All backends are bit-exact (integer associativity); spins are ±1 ``int8``,
 weights ``weight_bits``-bit signed carried in ``int8``, sums exact ``int32``.
@@ -68,11 +64,9 @@ import jax.numpy as jnp
 
 from repro.core import coupling as coupling_lib
 from repro.core import oscillator as osc
-from repro.core.checks import require_int_dtype
 from repro.core.quantization import check_weight_range
 
-_BACKEND_NAMES = ("parallel", "serial", "pallas", "hybrid")
-_HYBRID_IMPLS = ("scan", "pallas")
+_BACKEND_NAMES = ("parallel", "pallas", "hybrid")
 
 #: Auto ``parallel_factor`` (P) for ``backend="hybrid"`` when the config
 #: leaves it 0: wide enough that the serialized schedule is usable in
@@ -91,12 +85,8 @@ class ONNConfig:
 
     This is the only static argument of the functional API: everything
     numeric (weights, bias, phases) is traced.  ``backend`` selects the
-    weighted-sum schedule; ``__post_init__`` is the single documented entry
-    point for legacy-flag normalization — a bare ``serial_chunk > 0`` folds
-    into ``backend="serial"`` and a bare ``parallel_factor > 0`` into
-    ``backend="hybrid"``, so old and new spellings of one schedule hash
-    equal and share one jit executable.  (The ``use_kernel`` alias for
-    ``backend="pallas"``, deprecated since PR 1, has been removed.)
+    weighted-sum schedule, and ``parallel_factor`` is legal only with
+    ``backend="hybrid"``.
     """
 
     n: int
@@ -106,20 +96,14 @@ class ONNConfig:
     mode: str = "functional"  # "functional" | "rtl"
     max_cycles: int = 100
     sync_jitter: bool = False  # randomize enable-signal offset (rtl hybrid)
-    backend: str = "parallel"  # "parallel" | "serial" | "pallas" | "hybrid"
-    serial_chunk: int = 0  # block size for backend="serial" (0 → auto)
+    backend: str = "parallel"  # "parallel" | "pallas" | "hybrid"
     #: Parallelism P of the ``hybrid`` backend: the coupling sum is computed
     #: in ``ceil(n / P)`` serialized passes of P-wide integer MACs (the
     #: paper's serialized-MAC datapath with P parallel coupling elements).
     #: P=1 is the paper's single-MAC hybrid, P=n is one pass (the recurrent
     #: parallel schedule).  0 → auto (``DEFAULT_PARALLEL_FACTOR``, clamped
-    #: to n).  Setting it with ``backend="parallel"`` selects ``hybrid``.
+    #: to n).  Any other backend rejects a nonzero value.
     parallel_factor: int = 0
-    #: Execution route of the hybrid backend: ``"scan"`` — the ``lax.scan``
-    #: pass-by-pass reference (``hybrid_mac_sum``); ``"pallas"`` — the
-    #: blocked pass-group kernels (``repro.kernels.ops``), one launch per
-    #: pass-group with the real batch grid.  Bit-exact either way.
-    hybrid_impl: str = "scan"
     #: Cycles simulated between early-exit checks of the batched solve
     #: (``run_batch``/``retrieve``).  Every ``settle_chunk`` cycles the
     #: while-loop tests whether all lanes have frozen (settled, or in a
@@ -144,22 +128,6 @@ class ONNConfig:
             raise ValueError(f"unknown mode {self.mode!r}")
         if self.settle_chunk < 0:
             raise ValueError(f"settle_chunk must be >= 0, got {self.settle_chunk}")
-        # Legacy route flags map onto the backend field (they predate it and
-        # only ever selected one of these schedules).  The config is then
-        # normalized — backend is the canonical cache key, so an old-style
-        # and a new-style spelling of the same schedule hash equal and share
-        # one jit executable.  Contradictory combinations raise rather than
-        # silently dropping a flag.
-        if self.backend == "parallel" and self.serial_chunk > 0:
-            if self.parallel_factor > 0:
-                raise ValueError(
-                    "serial_chunk>0 and parallel_factor>0 are contradictory "
-                    "route flags; pick backend='serial' or backend='hybrid' "
-                    "explicitly"
-                )
-            object.__setattr__(self, "backend", "serial")
-        elif self.backend == "parallel" and self.parallel_factor > 0:
-            object.__setattr__(self, "backend", "hybrid")
         if self.backend not in _BACKEND_NAMES:
             raise ValueError(
                 f"unknown backend {self.backend!r}; expected one of {_BACKEND_NAMES}"
@@ -168,33 +136,14 @@ class ONNConfig:
             raise ValueError(
                 f"parallel_factor must be >= 0, got {self.parallel_factor}"
             )
-        if self.hybrid_impl not in _HYBRID_IMPLS:
+        if self.backend != "hybrid" and self.parallel_factor > 0:
+            # parallel_factor parameterizes only the hybrid schedule; a
+            # nonzero value on another backend is a config mistake, not a
+            # silent no-op (and would fork jit cache keys).
             raise ValueError(
-                f"unknown hybrid_impl {self.hybrid_impl!r}; expected one of "
-                f"{_HYBRID_IMPLS}"
+                f"parallel_factor={self.parallel_factor} only applies to "
+                f'backend="hybrid", not {self.backend!r}'
             )
-        if self.backend != "serial" and self.serial_chunk > 0:
-            # Same rule as parallel_factor/hybrid_impl below: a schedule knob
-            # on a backend that ignores it is a config mistake, and the dead
-            # field would fork jit cache keys.
-            raise ValueError(
-                f"serial_chunk={self.serial_chunk} only applies to "
-                f'backend="serial", not {self.backend!r}'
-            )
-        if self.backend != "hybrid":
-            # parallel_factor / hybrid_impl parameterize only the hybrid
-            # schedule; a non-default value on another backend is a config
-            # mistake, not a silent no-op (and would fork jit cache keys).
-            if self.parallel_factor > 0:
-                raise ValueError(
-                    f"parallel_factor={self.parallel_factor} only applies to "
-                    f'backend="hybrid", not {self.backend!r}'
-                )
-            if self.hybrid_impl != "scan":
-                raise ValueError(
-                    f"hybrid_impl={self.hybrid_impl!r} only applies to "
-                    f'backend="hybrid", not {self.backend!r}'
-                )
         if self.phase_pack and self.phase_bits > 4:
             raise ValueError(
                 f"phase_pack packs two phase counters per byte, which needs "
@@ -367,74 +316,18 @@ def _parallel_sum(cfg: ONNConfig, w: jax.Array, sigma: jax.Array) -> jax.Array:
     return coupling_lib.weighted_sum_parallel(w, sigma)
 
 
-def _serial_sum(cfg: ONNConfig, w: jax.Array, sigma: jax.Array) -> jax.Array:
-    chunk = cfg.serial_chunk if cfg.serial_chunk > 0 else min(cfg.n, 64)
-    return coupling_lib.weighted_sum_serial(w, sigma, chunk=chunk)
-
-
 def _pallas_sum(cfg: ONNConfig, w: jax.Array, sigma: jax.Array) -> jax.Array:
     from repro.kernels import ops as kernel_ops  # lazy: kernels are optional
 
     return kernel_ops.coupling_sum(w, sigma)
 
 
-def hybrid_mac_sum(w: jax.Array, sigma: jax.Array, parallel: int) -> jax.Array:
-    """Cycle-faithful serialized-MAC coupling sum (the hybrid datapath).
-
-    The ``lax.scan`` reference of the hybrid backend: the N-element input of
-    every oscillator row is consumed in ``ceil(N / parallel)`` passes, each
-    pass feeding ``parallel`` int8-carried weights and spins into a P-wide
-    MAC whose int32 accumulator is the scan carry — the executable model of
-    the paper's serialized coupling element generalized from one MAC (P=1)
-    to P parallel MAC lanes.  When ``parallel`` does not divide N the final
-    pass runs with zero-padded lanes (the hardware's idle MAC elements on
-    the ragged tail), which leaves the integer sum unchanged, so the result
-    is bit-exact with :func:`repro.core.coupling.weighted_sum_parallel` for
-    every P — at P=N the single pass *is* the parallel schedule.
-
-    ``w``: (N, N) int8; ``sigma``: (..., N) int8 in {−1, +1} → (..., N) int32.
-    """
-    if parallel <= 0:
-        raise ValueError(f"parallel must be positive, got {parallel}")
-    require_int_dtype(w, "w")
-    n_rows, n = w.shape
-    passes = -(-n // parallel)
-    pad = passes * parallel - n
-    if pad:
-        w = jnp.pad(w, ((0, 0), (0, pad)))
-        sigma = jnp.pad(sigma, [(0, 0)] * (sigma.ndim - 1) + [(0, pad)])
-    # (passes, N, P) weight slices / (passes, ..., P) spin slices: pass k
-    # streams columns [k·P, (k+1)·P) of every row through the MACs.
-    w_passes = (
-        w.astype(jnp.int32).reshape(n_rows, passes, parallel).transpose(1, 0, 2)
-    )
-    s_passes = jnp.moveaxis(
-        sigma.astype(jnp.int32).reshape(*sigma.shape[:-1], passes, parallel), -2, 0
-    )
-
-    def mac_pass(acc, slices):
-        wp, sp = slices  # (N, P), (..., P)
-        return (
-            acc + jnp.einsum("ip,...p->...i", wp, sp, preferred_element_type=jnp.int32),
-            None,
-        )
-
-    acc0 = jnp.zeros((*sigma.shape[:-1], n_rows), jnp.int32)
-    acc, _ = jax.lax.scan(mac_pass, acc0, (w_passes, s_passes))
-    return acc
-
-
 def _hybrid_sum(cfg: ONNConfig, w: jax.Array, sigma: jax.Array) -> jax.Array:
-    if cfg.hybrid_impl == "pallas":
-        from repro.kernels import ops as kernel_ops  # lazy: kernels are optional
-
-        return kernel_ops.hybrid_coupling_sum(w, sigma, parallel=cfg.hybrid_parallel)
-    return hybrid_mac_sum(w, sigma, cfg.hybrid_parallel)
+    return coupling_lib.weighted_sum_serial(w, sigma, chunk=cfg.hybrid_parallel)
 
 
 BACKENDS = {
     "parallel": _parallel_sum,
-    "serial": _serial_sum,
     "pallas": _pallas_sum,
     "hybrid": _hybrid_sum,
 }
@@ -462,9 +355,9 @@ def _model_sharded_sum(
 
     The software analogue of partitioning the coupling fabric across boards:
     W's rows are split over the ``"model"`` mesh axis, each device runs the
-    *configured backend* (parallel / serial / pallas / hybrid — so the fused
-    int8 MAC kernels execute per-device on their row block against the full
-    σ), scatters its partial fields into a zero buffer at its block offset,
+    *configured backend* (parallel / pallas / hybrid — so the int8 MAC
+    kernel executes per-device on its row block against the full σ),
+    scatters its partial fields into a zero buffer at its block offset,
     and a ``psum`` combines them.  The blocks are disjoint and the zeros of
     other devices are exact, so the integer combine is bit-exact with the
     single-device path for every backend — at any N, including N not
@@ -518,7 +411,9 @@ def weighted_sum(cfg: ONNConfig, w: jax.Array, sigma: jax.Array) -> jax.Array:
     Under an active model-sharded :class:`repro.distributed.ShardPlan` the
     backend runs per-device on its coupling-matrix row block inside a
     ``shard_map`` collective (:func:`_model_sharded_sum`) — bit-exact with
-    the single-device schedule.
+    the single-device schedule.  Every backend is bit-exact with
+    ``parallel``; ``hybrid`` serializes the sum into ``cfg.hybrid_passes``
+    P-wide MAC passes.
     """
     pm = _model_plan()
     if pm is not None:
@@ -553,8 +448,8 @@ def functional_update(cfg: ONNConfig, params: OnnParams, phase: jax.Array) -> ja
 
     Under a model-sharded ShardPlan the fused whole-cycle launches are
     bypassed — they need the full square W resident — and the cycle runs as
-    coupling collective + bias + alignment instead; the pallas/hybrid MAC
-    kernels still execute, per-device on their row block inside the
+    coupling collective + bias + alignment instead; the pallas MAC kernel
+    still executes, per-device on its row block inside the
     ``shard_map`` of :func:`_model_sharded_sum`.  Bit-exact either way.
     """
     model_sharded = _model_plan() is not None
@@ -571,18 +466,6 @@ def functional_update(cfg: ONNConfig, params: OnnParams, phase: jax.Array) -> ja
             params.weights, sigma, params.bias, phase, half=half
         )
     sigma = osc.spin(phase, cfg.phase_bits)
-    if cfg.backend == "hybrid" and cfg.hybrid_impl == "pallas" and not model_sharded:
-        from repro.kernels import ops as kernel_ops  # lazy: kernels are optional
-
-        half = osc.n_positions(cfg.phase_bits) // 2
-        return kernel_ops.hybrid_phase_step(
-            params.weights,
-            sigma,
-            params.bias,
-            phase,
-            half=half,
-            parallel=cfg.hybrid_parallel,
-        )
     s = weighted_sum(cfg, params.weights, sigma) + params.bias
     return osc.phase_align(phase, s, cfg.phase_bits)
 

@@ -13,9 +13,9 @@ Two solvers share this module:
   is kept as the small-N oracle and the benchmark baseline.
 * :func:`solve_maxcut_batch` — the batched, backend-native annealer.  A
   (replicas, N) spin state per instance advances through the *same*
-  ``weighted_sum`` backend table as retrieval (``parallel`` / ``serial`` /
-  ``pallas`` / ``hybrid`` with ``parallel_factor``), so Max-Cut runs on the
-  serialized-MAC datapath, the fused Pallas kernels, and under
+  ``weighted_sum`` backend table as retrieval (``parallel`` / ``pallas`` /
+  ``hybrid`` with ``parallel_factor``), so Max-Cut runs on the
+  serialized-MAC datapath, the Pallas kernels, and under
   ``constrain_onn`` sharding.  Asynchrony is modeled with **grouped
   staggered enables**: each sweep partitions the oscillators into K update
   groups (a fresh random partition per sweep, the hardware analogue of
@@ -333,7 +333,7 @@ def solve_maxcut_batch(
     ``cfg.max_cycles`` grouped-staggered sweeps (:func:`staggered_sweep`,
     K = ``stagger_groups``; 0 → ``min(DEFAULT_STAGGER_GROUPS, N)``), with
     every field evaluation dispatched through ``cfg.backend`` — results are
-    bit-exact across parallel/serial/pallas/hybrid for any
+    bit-exact across parallel/pallas/hybrid for any
     ``parallel_factor``.
 
     ``stagnation`` > 0 enables per-replica early exit, mirroring

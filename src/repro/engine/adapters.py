@@ -494,7 +494,7 @@ class MaxCutEngineSolver:
 
     Each request runs ``replicas`` independent anneals of ``sweeps``
     grouped-staggered sweeps through the configured ``backend``
-    (parallel / serial / pallas / hybrid with ``parallel_factor``), with
+    (parallel / pallas / hybrid with ``parallel_factor``), with
     optional per-replica early exit on cut-value ``stagnation``.  Compiles
     are keyed through the core's one-executable-per-(config, shape) jit
     story — per-bucket configs live in a dict bounded by the buckets
@@ -512,37 +512,32 @@ class MaxCutEngineSolver:
         stagnation: int = 0,
         backend: str = "parallel",
         parallel_factor: int = 0,
-        hybrid_impl: str = "scan",
         settle_chunk: int = 8,
     ):
         if solver is not None:  # wrap an api.MaxCutSolver's settings
             sweeps, weight_bits = solver.sweeps, solver.weight_bits
             replicas, stagger_groups = solver.replicas, solver.stagger_groups
             stagnation, backend = solver.stagnation, solver.backend
-            parallel_factor = solver.parallel_factor
-            hybrid_impl, settle_chunk = solver.hybrid_impl, solver.settle_chunk
+            parallel_factor, settle_chunk = solver.parallel_factor, solver.settle_chunk
         self.sweeps = int(sweeps)
         self.weight_bits = int(weight_bits)
         self.replicas = int(replicas)
         self.stagger_groups = int(stagger_groups)
         self.stagnation = int(stagnation)
         self.parallel_factor = int(parallel_factor)
-        self.hybrid_impl = str(hybrid_impl)
         self.settle_chunk = int(settle_chunk)
         if self.replicas < 1:
             raise ValueError(f"replicas must be >= 1, got {self.replicas}")
-        # Probe config: validates the backend/route combination once and
-        # normalizes legacy spellings (parallel_factor>0 selects hybrid).
-        probe = dynamics.ONNConfig(
+        # Probe config: validates the backend/parallel_factor combination once.
+        dynamics.ONNConfig(
             n=max(1, self.parallel_factor),
             weight_bits=self.weight_bits,
             max_cycles=self.sweeps,
             backend=str(backend),
             parallel_factor=self.parallel_factor,
-            hybrid_impl=self.hybrid_impl,
             settle_chunk=self.settle_chunk,
         )
-        self.backend = probe.backend
+        self.backend = str(backend)
         self._cfgs: Dict[int, dynamics.ONNConfig] = {}  # bounded: one per N bucket
 
     def _bucket_config(self, n_bucket: int) -> dynamics.ONNConfig:
@@ -553,7 +548,6 @@ class MaxCutEngineSolver:
                 max_cycles=self.sweeps,
                 backend=self.backend,
                 parallel_factor=self.parallel_factor,
-                hybrid_impl=self.hybrid_impl,
                 settle_chunk=self.settle_chunk,
             )
         return self._cfgs[n_bucket]
@@ -582,9 +576,7 @@ class MaxCutEngineSolver:
         # same weighted_sum kernels; warm the tuner on the replica-expanded
         # batch so the first solve at this bucket resolves blocks cache-hot.
         with spans.span(spans.PACK):
-            autotune.warm(
-                n=nb, batch=max(1, batch_bucket * self.replicas), kinds=("step", "hybrid")
-            )
+            autotune.warm(n=nb, batch=max(1, batch_bucket * self.replicas), kinds=("step",))
             cfg = self._bucket_config(nb)
             padded, true_n = [], []
             for p in payloads:

@@ -19,7 +19,7 @@ HBM round-trips per operand byte) subject to
   (:func:`repro.kernels.coupling_kernel.step_vmem_bytes`), would pass
   :data:`STEP_VMEM_BUDGET_BYTES`: on a v5e each grid step costs about
   0.36 µs whatever it moves, so a (32768 × 131072) W block streams at its
-  HBM roofline only in wide tiles.  ``"hybrid"`` and ``"matvec"`` shrink
+  HBM roofline only in wide tiles.  ``"matvec"`` shrinks
   from fixed preferences until one buffer of each operand
   (:func:`repro.kernels.coupling_kernel.vmem_bytes`) fits
   :data:`VMEM_BUDGET_BYTES`; ``"multi"`` sizes its resident W against
@@ -38,8 +38,8 @@ from typing import Dict, Iterator, NamedTuple, Tuple
 
 from repro.kernels import coupling_kernel as _k
 
-#: Budget of the ``"hybrid"`` and ``"matvec"`` tiles, one buffer of each
-#: operand counted (:func:`repro.kernels.coupling_kernel.vmem_bytes`).
+#: Budget of the ``"matvec"`` tiles, one buffer of each operand counted
+#: (:func:`repro.kernels.coupling_kernel.vmem_bytes`).
 VMEM_BUDGET_BYTES = (16 * 2**20) // 4
 
 #: Budget of one ``"step"`` grid step with both pipeline buffers of every
@@ -61,7 +61,7 @@ MULTI_VMEM_BUDGET_BYTES = 14 * 2**20
 MULTI_KERNEL_MAX_N = 2048
 
 #: Kinds a block tuple can be tuned for; one cache entry per (kind, bucket).
-KINDS = ("step", "hybrid", "matvec", "multi")
+KINDS = ("step", "matvec", "multi")
 
 #: The (N, batch) grid the serving/engine stack actually buckets to; the
 #: static VMEM checker (``repro.analysis.vmem``) and the kernel benchmarks
@@ -182,20 +182,13 @@ def blocks_for(kind: str, *, n: int, batch: int, m: int | None = None) -> BlockC
         bm = _lane_tile(m, _k.DEFAULT_BLOCK_I)
         bk = _pick(n, 512, minimum=128)
         cfg = _shrink_to_budget(bb, bm, bk, minimum=8)
-    elif kind == "hybrid":
-        # block_k is the target width of a serialized MAC pass-group
-        # (``hybrid_pass_groups``), a parameter of the FPGA cycle model, so
-        # these tiles keep their fixed preferences.
-        bi = _lane_tile(m, 256 if m >= 256 else 128)
-        bk = _lane_tile(n, 256 if n >= 256 else 128)
-        cfg = _shrink_to_budget(bb, bi, bk)
     else:
         cfg = _step_tiles(bb, m, n)
     _CACHE[key] = cfg
     return cfg
 
 
-def warm(*, n: int, batch: int, kinds: Tuple[str, ...] = ("step", "hybrid", "multi")) -> None:
+def warm(*, n: int, batch: int, kinds: Tuple[str, ...] = ("step", "multi")) -> None:
     """Pre-resolve the block tuples for one bucket (engine install time).
 
     Idempotent and cheap; the point is that every later kernel call for this
@@ -229,8 +222,7 @@ def iter_buckets(
 
 
 def grid_steps(kind: str, m: int, n: int, batch: int, cfg: BlockConfig) -> int:
-    """Grid steps the tiles cut a ``(kind, m, n, batch)`` operand set into
-    (for ``"hybrid"``, summed over its pass-group launches at P ≤ block_k)."""
+    """Grid steps the tiles cut a ``(kind, m, n, batch)`` operand set into."""
     steps = -(-batch // cfg.block_b)
     if kind != "multi":
         steps *= -(-m // cfg.block_i) * -(-n // cfg.block_k)

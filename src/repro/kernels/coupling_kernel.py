@@ -41,7 +41,7 @@ def pad_to_blocks(x: jax.Array, multiples, value=0) -> jax.Array:
     """Zero-pad each axis of ``x`` up to the next multiple of ``multiples``.
 
     ``multiples`` is one int per axis (0/1 → leave the axis alone).  This is
-    the same treatment the serial backend gives N not divisible by its chunk:
+    the same treatment the hybrid backend gives N not divisible by its P:
     padded rows/columns carry zeros, which contribute nothing to the integer
     sums, so callers can slice the result back to the original extent.  The
     ``*_pallas`` entry points below require pre-padded shapes and point here
@@ -664,174 +664,6 @@ def phase_step_multi_pallas(
         frozen,
         frozen_p2,
         freeze_cycle,
-    )
-
-
-# ---------------------------------------------------------------------------
-# Hybrid serialized-MAC coupling: the paper's hybrid datapath as a sequence
-# of blocked kernel launches.  The coupling sum is serialized into
-# ceil(N / P) passes of P-wide MACs; passes are grouped so that each *pass-
-# group* (as many passes as fill one hardware-aligned contraction block) is
-# ONE kernel launch streaming its weight slice HBM→VMEM — the TPU image of
-# the FPGA's fast-clock counter walking BRAM rows.  The int32 MAC
-# accumulator is carried *between* launches (donated via
-# input_output_aliases), and the final launch fuses the bias + phase-align
-# epilogue.  Batch is a real grid dimension in every launch.
-# ---------------------------------------------------------------------------
-
-
-def hybrid_pass_groups(parallel: int, target_block_k: int = DEFAULT_BLOCK_K):
-    """(passes_per_group, group width) for a serialized-MAC launch schedule.
-
-    Each launch covers as many P-wide passes as fit the target contraction
-    block; a P wider than the target runs one pass per launch.
-    """
-    if parallel <= 0:
-        raise ValueError(f"parallel must be positive, got {parallel}")
-    passes_per_group = max(1, target_block_k // parallel)
-    return passes_per_group, passes_per_group * parallel
-
-
-def _hybrid_mac_pass_kernel(sigma_ref, w_ref, acc_ref, out_ref):
-    """One pass-group: out = acc + σ_g · W_gᵀ (exact int32 accumulation)."""
-    out_ref[...] = acc_ref[...] + jax.lax.dot_general(
-        sigma_ref[...],
-        w_ref[...],
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
-
-
-def _hybrid_phase_epilogue_kernel(
-    half: int, sigma_ref, w_ref, acc_ref, bias_ref, phase_ref, out_ref
-):
-    """Final pass-group fused with the bias + phase-align epilogue."""
-    s = (
-        acc_ref[...]
-        + jax.lax.dot_general(
-            sigma_ref[...],
-            w_ref[...],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
-        + bias_ref[...].astype(jnp.int32)
-    )
-    keep = phase_ref[...]
-    out_ref[...] = jnp.where(
-        s > 0, jnp.int32(0), jnp.where(s < 0, jnp.int32(half), keep)
-    )
-
-
-def _hybrid_launch_shapes(sigma, w, parallel, block_b, block_i, block_k):
-    b, n = sigma.shape
-    ni, nk = w.shape
-    _require(n == nk, f"hybrid: sigma N={n} != weights N={nk}")
-    _, width = hybrid_pass_groups(parallel, block_k)
-    _require(
-        b % block_b == 0 and ni % block_i == 0 and nk % width == 0,
-        f"hybrid: shapes (b={b}, ni={ni}, nk={nk}) not multiples of "
-        f"(block_b={block_b}, block_i={block_i}, pass-group width={width}); "
-        "pad with pad_to_blocks",
-    )
-    return b, ni, nk, width
-
-
-def _hybrid_pass_call(kernel, extra_specs, out_dtype, b, ni, width, block_b, block_i, interpret):
-    grid = (ni // block_i, b // block_b)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, width), lambda i, bb: (bb, 0)),
-            pl.BlockSpec((block_i, width), lambda i, bb: (i, 0)),
-            pl.BlockSpec((block_b, block_i), lambda i, bb: (bb, i)),
-            *extra_specs,
-        ],
-        out_specs=pl.BlockSpec((block_b, block_i), lambda i, bb: (bb, i)),
-        out_shape=jax.ShapeDtypeStruct((b, ni), out_dtype),
-        input_output_aliases={2: 0},  # the MAC accumulator is donated through
-        interpret=interpret,
-    )
-
-
-def hybrid_coupling_sum_pallas(
-    sigma: jax.Array,
-    w: jax.Array,
-    *,
-    parallel: int,
-    block_b: int = DEFAULT_BLOCK_B,
-    block_i: int = DEFAULT_BLOCK_I,
-    block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = False,
-) -> jax.Array:
-    """S[b,i] = Σ_j W[i,j] σ[b,j] through the serialized pass-group schedule.
-
-    One kernel launch per pass-group (``hybrid_pass_groups``); the int32
-    accumulator rides between launches.  Shapes must be pre-padded: batch to
-    ``block_b``, rows to ``block_i``, columns to the pass-group width.
-    """
-    b, ni, nk, width = _hybrid_launch_shapes(sigma, w, parallel, block_b, block_i, block_k)
-    acc = jnp.zeros((b, ni), jnp.int32)
-    call = _hybrid_pass_call(
-        _hybrid_mac_pass_kernel, [], jnp.int32, b, ni, width, block_b, block_i, interpret
-    )
-    for g in range(nk // width):
-        sl = slice(g * width, (g + 1) * width)
-        acc = call(sigma[:, sl], w[:, sl], acc)
-    return acc
-
-
-def hybrid_phase_step_pallas(
-    sigma: jax.Array,
-    w: jax.Array,
-    bias: jax.Array,
-    phase: jax.Array,
-    *,
-    half: int,
-    parallel: int,
-    block_b: int = DEFAULT_BLOCK_B,
-    block_i: int = DEFAULT_BLOCK_I,
-    block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = False,
-) -> jax.Array:
-    """Fused hybrid functional-mode cycle: serialized MAC pass-groups, then
-    θ' = phase-align(S + h, θ) in the final launch's epilogue.
-
-    Same contract as :func:`phase_step_pallas` (``phase`` int32 counters,
-    S == 0 keeps the phase), but the contraction runs as one launch per
-    pass-group with the accumulator carried between launches.
-    """
-    b, ni, nk, width = _hybrid_launch_shapes(sigma, w, parallel, block_b, block_i, block_k)
-    _require(bias.shape == (ni,), f"hybrid_phase_step: bias {bias.shape} != ({ni},)")
-    _require(
-        phase.shape == (b, ni),
-        f"hybrid_phase_step: phase {phase.shape} != ({b}, {ni})",
-    )
-    groups = nk // width
-    acc = jnp.zeros((b, ni), jnp.int32)
-    mac_call = _hybrid_pass_call(
-        _hybrid_mac_pass_kernel, [], jnp.int32, b, ni, width, block_b, block_i, interpret
-    )
-    for g in range(groups - 1):
-        sl = slice(g * width, (g + 1) * width)
-        acc = mac_call(sigma[:, sl], w[:, sl], acc)
-    epilogue_call = _hybrid_pass_call(
-        functools.partial(_hybrid_phase_epilogue_kernel, half),
-        [
-            pl.BlockSpec((1, block_i), lambda i, bb: (0, i)),
-            pl.BlockSpec((block_b, block_i), lambda i, bb: (bb, i)),
-        ],
-        jnp.int32,
-        b,
-        ni,
-        width,
-        block_b,
-        block_i,
-        interpret,
-    )
-    sl = slice((groups - 1) * width, groups * width)
-    return epilogue_call(
-        sigma[:, sl], w[:, sl], acc, bias.reshape(1, -1), phase.astype(jnp.int32)
     )
 
 

@@ -389,121 +389,6 @@ def phase_step_multi(
     )
 
 
-@functools.partial(
-    jax.jit, static_argnames=("parallel", "use_pallas", "block_b", "block_i", "block_k")
-)
-def _hybrid_coupling_sum_jit(w, sigma, *, parallel, use_pallas, block_b, block_i, block_k):
-    TRACE_COUNTER["hybrid_coupling_sum"] += 1
-    _require_int_dtype(w, "w")
-    squeeze = sigma.ndim == 1
-    batch_shape = sigma.shape[:-1]
-    m, n = w.shape
-    sig2d = sigma.reshape(-1, n).astype(jnp.int8)
-    if not use_pallas:
-        out = _ref.hybrid_coupling_sum_ref(w, sig2d, parallel)
-    else:
-        _, width = _k.hybrid_pass_groups(parallel, block_k)
-        sig_p = _k.pad_to_blocks(sig2d, (block_b, width))
-        w_p = _k.pad_to_blocks(w.astype(jnp.int8), (block_i, width))
-        out = _k.hybrid_coupling_sum_pallas(
-            sig_p, w_p, parallel=parallel, block_b=block_b, block_i=block_i,
-            block_k=block_k, interpret=_interpret(),
-        )[: sig2d.shape[0], :m]
-    return out.reshape(m) if squeeze else out.reshape(*batch_shape, m)
-
-
-def hybrid_coupling_sum(
-    w: jax.Array,
-    sigma: jax.Array,
-    *,
-    parallel: int,
-    use_pallas: bool = True,
-    block_b: int | None = None,
-    block_i: int | None = None,
-    block_k: int | None = None,
-) -> jax.Array:
-    """S = W σ through the hybrid serialized pass-group schedule.
-
-    ``parallel`` is the MAC width P: the contraction serializes into
-    ``ceil(N / P)`` passes, grouped so every kernel launch covers one
-    hardware-aligned pass-group (``repro.kernels.coupling_kernel``).
-    Bit-exact with :func:`coupling_sum` for every P.  Like
-    :func:`coupling_sum`, ``w`` may be a (M, N) row slab.
-    """
-    m, n = w.shape
-    bb, bi, bk = _resolve_blocks(
-        "hybrid", _batch_extent(sigma), m, n, block_b, block_i, block_k
-    )
-    return _hybrid_coupling_sum_jit(
-        w, sigma, parallel=parallel, use_pallas=use_pallas,
-        block_b=bb, block_i=bi, block_k=bk,
-    )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("half", "parallel", "use_pallas", "block_b", "block_i", "block_k"),
-)
-def _hybrid_phase_step_jit(
-    w, sigma, bias, phase, *, half, parallel, use_pallas, block_b, block_i, block_k
-):
-    TRACE_COUNTER["hybrid_phase_step"] += 1
-    _require_int_dtype(w, "w")
-    _require_int_dtype(bias, "bias")
-    squeeze = sigma.ndim == 1
-    batch_shape = sigma.shape[:-1]
-    n = w.shape[0]
-    sig2d = sigma.reshape(-1, n).astype(jnp.int8)
-    ph2d = phase.reshape(-1, n).astype(jnp.int32)
-    h = jnp.zeros((n,), jnp.int32) if bias is None else bias.astype(jnp.int32)
-    if not use_pallas:
-        out = _ref.hybrid_phase_step_ref(w, sig2d, h, ph2d, half, parallel)
-    else:
-        _, width = _k.hybrid_pass_groups(parallel, block_k)
-        sig_p = _k.pad_to_blocks(sig2d, (block_b, width))
-        w_p = _k.pad_to_blocks(w.astype(jnp.int8), (block_i, width))
-        h_p = _k.pad_to_blocks(h, (block_i,))
-        ph_p = _k.pad_to_blocks(ph2d, (block_b, block_i))
-        out = _k.hybrid_phase_step_pallas(
-            sig_p, w_p, h_p, ph_p,
-            half=half, parallel=parallel,
-            block_b=block_b, block_i=block_i, block_k=block_k,
-            interpret=_interpret(),
-        )[: sig2d.shape[0], :n]
-    out = out.astype(phase.dtype)
-    return out.reshape(n) if squeeze else out.reshape(*batch_shape, n)
-
-
-def hybrid_phase_step(
-    w: jax.Array,
-    sigma: jax.Array,
-    bias: jax.Array | None,
-    phase: jax.Array,
-    *,
-    half: int,
-    parallel: int,
-    use_pallas: bool = True,
-    block_b: int | None = None,
-    block_i: int | None = None,
-    block_k: int | None = None,
-) -> jax.Array:
-    """Fused hybrid functional-mode cycle: θ' = phase-align(W σ + h, θ) with
-    the coupling sum serialized into pass-group launches of MAC width
-    ``parallel``.  Same calling convention as :func:`phase_step`; the
-    batched ONN hot path (backend="hybrid", hybrid_impl="pallas") lands
-    here with the request batch as a real grid dimension.
-    """
-    n = w.shape[0]
-    bb, bi, bk = _resolve_blocks(
-        "hybrid", _batch_extent(sigma), n, n, block_b, block_i, block_k
-    )
-    return _hybrid_phase_step_jit(
-        w, sigma, bias, phase,
-        half=half, parallel=parallel, use_pallas=use_pallas,
-        block_b=bb, block_i=bi, block_k=bk,
-    )
-
-
 @functools.partial(jax.jit, static_argnames=("use_pallas", "block_b", "block_m", "block_k"))
 def _quantized_matvec_jit(w_q, scale, x, *, use_pallas, block_b, block_m, block_k):
     TRACE_COUNTER["quantized_matvec"] += 1

@@ -54,42 +54,6 @@ def phase_step_ref(
     )
 
 
-def hybrid_coupling_sum_ref(w: jax.Array, sigma: jax.Array, parallel: int) -> jax.Array:
-    """Serialized-MAC coupling sum, pass by pass (hybrid datapath oracle).
-
-    An explicit Python loop over the ``ceil(N / parallel)`` passes — each
-    pass accumulates a ``parallel``-wide slice of every row into the int32
-    accumulator, including the ragged final pass — deliberately independent
-    of both the ``lax.scan`` reference and the pass-group kernels it checks.
-    """
-    if parallel <= 0:
-        raise ValueError(f"parallel must be positive, got {parallel}")
-    n = w.shape[1]
-    acc = jnp.zeros((sigma.shape[0], w.shape[0]), jnp.int32)
-    for start in range(0, n, parallel):
-        wp = w[:, start : start + parallel].astype(jnp.int32)
-        sp = sigma[:, start : start + parallel].astype(jnp.int32)
-        acc = acc + jnp.einsum("ip,bp->bi", wp, sp, preferred_element_type=jnp.int32)
-    return acc
-
-
-def hybrid_phase_step_ref(
-    w: jax.Array,
-    sigma: jax.Array,
-    bias: jax.Array,
-    phase: jax.Array,
-    half: int,
-    parallel: int,
-) -> jax.Array:
-    """Serialized-MAC coupling sum + the phase-align epilogue (int32 phases)."""
-    s = hybrid_coupling_sum_ref(w, sigma, parallel) + require_int_dtype(
-        bias, "bias"
-    ).astype(jnp.int32)[None, :]
-    return jnp.where(
-        s > 0, jnp.int32(0), jnp.where(s < 0, jnp.int32(half), phase.astype(jnp.int32))
-    )
-
-
 def phase_step_packed_ref(
     w: jax.Array, bias: jax.Array, phase: jax.Array, half: int
 ) -> jax.Array:

@@ -5,9 +5,8 @@ Generates a stream of Erdős–Rényi instances, installs a batched
 as one request.  The engine coalesces instances into shape-bucketed slabs;
 the batched annealer (``repro.core.ising.solve_maxcut_batch``) runs every
 slab through the configured weighted-sum backend — ``--backend hybrid
---parallel-factor P`` computes with the paper's serialized-MAC datapath,
-``--hybrid-impl pallas`` with the fused pass-group kernels — with
-``--replicas`` independent anneals per instance and ``--stagger-groups``
+--parallel-factor P`` computes with the paper's serialized-MAC datapath —
+with ``--replicas`` independent anneals per instance and ``--stagger-groups``
 update groups per sweep (N = fully asynchronous, small K = the
 parallelization trade).  Bucket padding is bit-identical on the real
 vertices: the same (instance, seed) returns the same cut under every
@@ -136,11 +135,10 @@ def main() -> None:
                          "(0 = run all sweeps)")
     ap.add_argument("--weight-bits", type=int, default=5)
     ap.add_argument("--backend", default="parallel",
-                    choices=["parallel", "serial", "pallas", "hybrid"],
+                    choices=["parallel", "pallas", "hybrid"],
                     help="weighted-sum schedule for the coupling field")
     ap.add_argument("--parallel-factor", type=int, default=0,
                     help="MAC width P of --backend hybrid (0 = auto)")
-    ap.add_argument("--hybrid-impl", default="scan", choices=["scan", "pallas"])
     ap.add_argument("--settle-chunk", type=int, default=8, help="sweeps between early-exit checks")
     ap.add_argument("--n-policy", default="pow2",
                     help='engine N bucketing: "pow2", "exact", or comma sizes')
@@ -166,7 +164,6 @@ def main() -> None:
         stagnation=args.stagnation,
         backend=args.backend,
         parallel_factor=args.parallel_factor,
-        hybrid_impl=args.hybrid_impl,
         settle_chunk=args.settle_chunk,
     )
     policy: Any = args.n_policy

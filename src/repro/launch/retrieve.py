@@ -55,7 +55,6 @@ def build_solver(
     backend: str = "parallel",
     settle_chunk: int = 8,
     parallel_factor: int = 0,
-    hybrid_impl: str = "scan",
 ) -> Tuple[RetrievalSolver, jax.Array]:
     """Train a solver for one letter dataset; returns (solver, patterns)."""
     xi = pat.load_dataset(dataset)  # (P, N) ±1
@@ -69,7 +68,6 @@ def build_solver(
         backend=backend,
         settle_chunk=settle_chunk,
         parallel_factor=parallel_factor,
-        hybrid_impl=hybrid_impl,
     )
     return solver, xi
 
@@ -209,14 +207,11 @@ def main() -> None:
     ap.add_argument("--corruption", type=float, default=0.25)
     ap.add_argument("--requests", type=int, default=256)
     ap.add_argument("--backend", default="parallel",
-                    choices=["parallel", "serial", "pallas", "hybrid"],
+                    choices=["parallel", "pallas", "hybrid"],
                     help="weighted-sum schedule for the coupling sum")
     ap.add_argument("--parallel-factor", type=int, default=0,
                     help="MAC width P of --backend hybrid: the coupling sum "
                          "serializes into ceil(N/P) passes (0 = auto)")
-    ap.add_argument("--hybrid-impl", default="scan", choices=["scan", "pallas"],
-                    help="execution route of --backend hybrid: lax.scan "
-                         "reference or blocked pass-group Pallas kernels")
     ap.add_argument("--settle-chunk", type=int, default=8,
                     help="cycles between early-exit checks (0 = fixed scan)")
     ap.add_argument("--mesh", default=None, metavar="BxM",
@@ -237,7 +232,6 @@ def main() -> None:
     solver, xi = build_solver(
         args.dataset, args.architecture, args.mode, backend=args.backend,
         settle_chunk=args.settle_chunk, parallel_factor=args.parallel_factor,
-        hybrid_impl=args.hybrid_impl,
     )
     policy: Any = args.n_policy
     if policy not in ("pow2", "exact"):

@@ -171,7 +171,7 @@ def main() -> None:
     ap.add_argument("--no-qat", action="store_true",
                     help="train float DO-I instead of quantization-aware DO-I")
     ap.add_argument("--backend", default="parallel",
-                    choices=("parallel", "serial", "pallas", "hybrid"))
+                    choices=("parallel", "pallas", "hybrid"))
     ap.add_argument("--settle-chunk", type=int, default=4)
     args = ap.parse_args()
     ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="onn_ckpt_")

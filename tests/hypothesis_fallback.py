@@ -4,10 +4,10 @@
 Mixed test modules guard their import with::
 
     try:
-        from hypothesis import given, settings
+        from hypothesis import example, given, settings
         from hypothesis import strategies as st
     except ModuleNotFoundError:
-        from hypothesis_fallback import given, settings, st
+        from hypothesis_fallback import example, given, settings, st
 
 so their non-property tests still collect and run; each ``@given`` test is
 marked skipped instead of failing collection.  (Modules that are *entirely*
@@ -20,6 +20,13 @@ import pytest
 def given(*_args, **_kwargs):
     def deco(fn):
         return pytest.mark.skip(reason="hypothesis not installed")(fn)
+
+    return deco
+
+
+def example(*_args, **_kwargs):
+    def deco(fn):
+        return fn
 
     return deco
 

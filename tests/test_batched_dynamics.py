@@ -16,10 +16,10 @@ import numpy as np
 import pytest
 
 try:
-    from hypothesis import given, settings
+    from hypothesis import example, given, settings
     from hypothesis import strategies as st
 except ModuleNotFoundError:
-    from hypothesis_fallback import given, settings, st
+    from hypothesis_fallback import example, given, settings, st
 
 from repro import api
 from repro.core import dynamics
@@ -75,13 +75,13 @@ def _assert_results_equal(got, ref, msg=""):
         ("parallel", "functional", "recurrent", 8),
         ("parallel", "rtl", "hybrid", 4),
         ("parallel", "rtl", "recurrent", 8),
-        ("serial", "functional", "hybrid", 3),
-        ("serial", "rtl", "hybrid", 5),
         ("pallas", "functional", "hybrid", 8),
+        ("pallas", "functional", "recurrent", 3),
         ("pallas", "rtl", "hybrid", 8),
         ("hybrid", "functional", "hybrid", 4),
         ("hybrid", "rtl", "hybrid", 3),
         ("hybrid", "functional", "recurrent", 8),
+        ("hybrid", "rtl", "recurrent", 5),
     ],
 )
 def test_retrieve_bit_exact_with_fixed_scan(backend, mode, architecture, settle_chunk):
@@ -93,7 +93,6 @@ def test_retrieve_bit_exact_with_fixed_scan(backend, mode, architecture, settle_
     cfg = dynamics.ONNConfig(
         n=n,
         backend=backend,
-        serial_chunk=5 if backend == "serial" else 0,
         parallel_factor=5 if backend == "hybrid" else 0,  # ragged: 5 ∤ 12
         mode=mode,
         architecture=architecture,
@@ -185,21 +184,19 @@ def test_early_exit_stops_scanning(monkeypatch):
 
 
 def test_batched_backends_bit_exact():
-    """The (B,N)-first dispatch keeps all four schedules bit-exact."""
+    """The (B,N)-first dispatch keeps all three schedules bit-exact."""
     w, b, sigma0 = _instance(21, 20, batch=4)
     results = {}
-    for backend in ("parallel", "serial", "pallas", "hybrid"):
+    for backend in ("parallel", "pallas", "hybrid"):
         cfg = dynamics.ONNConfig(
             n=20,
             backend=backend,
-            serial_chunk=7 if backend == "serial" else 0,
             parallel_factor=7 if backend == "hybrid" else 0,  # ragged: 7 ∤ 20
             max_cycles=15,
             settle_chunk=4,
         )
         params = dynamics.make_params(cfg, w, b)
         results[backend] = dynamics.retrieve(cfg, params, sigma0)
-    _assert_results_equal(results["serial"], results["parallel"])
     _assert_results_equal(results["pallas"], results["parallel"])
     _assert_results_equal(results["hybrid"], results["parallel"])
 
@@ -212,25 +209,24 @@ def test_batched_backends_bit_exact():
 @settings(max_examples=20, deadline=None)
 @given(
     seed=st.integers(0, 2**16),
-    backend=st.sampled_from(["parallel", "serial", "pallas", "hybrid"]),
+    backend=st.sampled_from(["parallel", "pallas", "hybrid"]),
     mode=st.sampled_from(["functional", "rtl"]),
     settle_chunk=st.integers(1, 9),
 )
+# N=4, P=1, recurrent, rtl: the draw that once failed XLA's CPU compiler.
+@example(seed=0, backend="hybrid", mode="rtl", settle_chunk=1)
 def test_property_early_exit_bit_exact(seed, backend, mode, settle_chunk):
     """Chunked while_loop ≡ fixed-length scan, bit for bit, on random int8
     couplings (phases, settle_cycle, settled, cycled) — rtl draws jitter from
     a pinned key so the comparison covers the randomized path too.  The
-    hybrid backend draws a random MAC width (ragged tails included) and
-    alternates between its scan and pallas execution routes."""
+    hybrid backend draws a random MAC width (ragged tails included)."""
     n = 4 + seed % 9
     w, b, sigma0 = _instance(seed, n, batch=4)
     jitter = mode == "rtl"
     cfg = dynamics.ONNConfig(
         n=n,
         backend=backend,
-        serial_chunk=1 + seed % 5 if backend == "serial" else 0,
         parallel_factor=1 + seed % (n + 1) if backend == "hybrid" else 0,
-        hybrid_impl=("pallas" if seed % 3 == 0 else "scan") if backend == "hybrid" else "scan",
         mode=mode,
         architecture="hybrid" if seed % 2 else "recurrent",
         max_cycles=10,

@@ -86,55 +86,41 @@ def test_vmap_over_params_many_problems_one_compile():
 
 @pytest.mark.parametrize("n,chunk", [(20, 4), (20, 7), (9, 2), (48, 5)])
 def test_backends_bit_exact(n, chunk):
-    """serial (any chunk, divisible or not), pallas and hybrid (both impls,
-    the chunk doubling as a ragged MAC width P) match parallel."""
+    """pallas and hybrid (the chunk doubling as a ragged MAC width P) match
+    parallel."""
     w, b, sigma0 = _instance(n * 100 + chunk, n, bias=True)
     batch = jnp.stack([sigma0, -sigma0])
     results = {}
     specs = {
         "parallel": {},
-        "serial": {"serial_chunk": chunk},
         "pallas": {},
-        "hybrid-scan": {
-            "parallel_factor": chunk, "hybrid_impl": "scan", "_backend": "hybrid"
-        },
-        "hybrid-pallas": {
-            "parallel_factor": chunk, "hybrid_impl": "pallas", "_backend": "hybrid"
-        },
+        "hybrid": {"parallel_factor": chunk},
     }
     for name, kw in specs.items():
-        backend = kw.pop("_backend", name)
-        cfg = api.ONNConfig(n=n, backend=backend, max_cycles=20, **kw)
+        cfg = api.ONNConfig(n=n, backend=name, max_cycles=20, **kw)
         params = api.make_params(cfg, w, b)
         results[name] = np.asarray(api.retrieve(cfg, params, batch).final_sigma)
-    for name in ("serial", "pallas", "hybrid-scan", "hybrid-pallas"):
+    for name in ("pallas", "hybrid"):
         np.testing.assert_array_equal(results["parallel"], results[name], err_msg=name)
 
 
-def test_legacy_route_flags_map_to_backend():
+@pytest.mark.parametrize(
+    "kwargs,error",
+    [
+        ({"backend": "serial"}, ValueError),
+        ({"serial_chunk": 2}, TypeError),
+        ({"hybrid_impl": "pallas"}, TypeError),
+        ({"parallel_factor": 4}, ValueError),
+        ({"use_kernel": True}, TypeError),
+    ],
+    ids=["backend-serial", "serial_chunk", "hybrid_impl", "bare-parallel_factor", "use_kernel"],
+)
+def test_removed_route_spellings_raise(kwargs, error):
+    """One spelling per schedule: removed backends, route options and the
+    bare ``parallel_factor`` (which once selected hybrid) are errors."""
     assert api.ONNConfig(n=4).backend == "parallel"
-    assert api.ONNConfig(n=4, serial_chunk=2).backend == "serial"
-    assert api.ONNConfig(n=4, parallel_factor=8).backend == "hybrid"
-    with pytest.raises(ValueError):
-        api.ONNConfig(n=4, backend="systolic")
-    # contradictory combinations raise instead of silently dropping a flag
-    with pytest.raises(ValueError, match="contradictory"):
-        api.ONNConfig(n=4, serial_chunk=2, parallel_factor=8)
-    # the use_kernel alias (deprecated since PR 1) is gone for good
-    with pytest.raises(TypeError, match="use_kernel"):
-        api.ONNConfig(n=4, use_kernel=True)
-
-
-def test_legacy_and_canonical_spellings_share_a_cache_key():
-    """Old-style and new-style configs of the same schedule must hash equal,
-    or jit(static_argnums=0) would compile the same program twice.  The
-    old-style spelling normalizes in __post_init__."""
-    assert api.ONNConfig(n=4, serial_chunk=2) == api.ONNConfig(
-        n=4, backend="serial", serial_chunk=2
-    )
-    assert hash(api.ONNConfig(n=4, parallel_factor=8)) == hash(
-        api.ONNConfig(n=4, backend="hybrid", parallel_factor=8)
-    )
+    with pytest.raises(error, match=next(iter(kwargs))):
+        api.ONNConfig(n=4, **kwargs)
 
 
 def test_step_rejects_rtl_mode():

@@ -1,9 +1,8 @@
 """The hybrid serialized-MAC backend and its satellites.
 
 Acceptance surface of the cycle-faithful hybrid datapath: config semantics
-of ``parallel_factor``/``hybrid_impl``, bit-exactness against the parallel
-backend across MAC widths (ragged tails, P=N degeneracy) on both execution
-routes, masked-lane padding, the P-aware engine cost model and FPGA trade
+of ``parallel_factor``, bit-exactness against the parallel backend across
+MAC widths (ragged tails, P=N degeneracy) in both modes, masked-lane padding, the P-aware engine cost model and FPGA trade
 quotes, the CLI plumbing, and the bench-regression gate's compare logic.
 """
 
@@ -42,7 +41,10 @@ def _assert_results_equal(got, ref, msg=""):
 
 
 def test_parallel_factor_selects_hybrid_backend():
-    cfg = dynamics.ONNConfig(n=16, parallel_factor=4)
+    # parallel_factor no longer selects the backend on its own
+    with pytest.raises(ValueError, match="parallel_factor"):
+        dynamics.ONNConfig(n=16, parallel_factor=4)
+    cfg = dynamics.ONNConfig(n=16, backend="hybrid", parallel_factor=4)
     assert cfg.backend == "hybrid"
     assert cfg.hybrid_parallel == 4
     assert cfg.hybrid_passes == 4
@@ -63,23 +65,12 @@ def test_parallel_factor_auto_and_clamp():
 
 
 def test_contradictory_route_flags_raise():
-    with pytest.raises(ValueError, match="contradictory"):
-        dynamics.ONNConfig(n=8, serial_chunk=2, parallel_factor=4)
-    with pytest.raises(ValueError, match="parallel_factor"):
-        dynamics.ONNConfig(n=8, backend="serial", serial_chunk=2, parallel_factor=4)
     with pytest.raises(ValueError, match="parallel_factor"):
         dynamics.ONNConfig(n=8, backend="pallas", parallel_factor=4)
-    with pytest.raises(ValueError, match="hybrid_impl"):
-        dynamics.ONNConfig(n=8, backend="parallel", hybrid_impl="pallas")
-    with pytest.raises(ValueError, match="hybrid_impl"):
-        dynamics.ONNConfig(n=8, backend="hybrid", hybrid_impl="mxu")
+    with pytest.raises(ValueError, match="parallel_factor"):
+        dynamics.ONNConfig(n=8, backend="parallel", parallel_factor=4)
     with pytest.raises(ValueError, match="parallel_factor"):
         dynamics.ONNConfig(n=8, backend="hybrid", parallel_factor=-2)
-    # the same dead-knob rule covers serial_chunk on non-serial backends
-    with pytest.raises(ValueError, match="serial_chunk"):
-        dynamics.ONNConfig(n=8, backend="hybrid", parallel_factor=4, serial_chunk=3)
-    with pytest.raises(ValueError, match="serial_chunk"):
-        dynamics.ONNConfig(n=8, backend="pallas", serial_chunk=3)
 
 
 def test_pad_config_freezes_the_resolved_mac_width():
@@ -94,46 +85,39 @@ def test_pad_config_freezes_the_resolved_mac_width():
     assert dynamics.pad_config(clamped, 16).hybrid_parallel == 10
 
 
-def test_hybrid_spellings_share_a_cache_key():
-    """The coerced and explicit spellings of one hybrid schedule hash equal
-    (jit static_argnums=0 would otherwise compile the program twice)."""
-    a = dynamics.ONNConfig(n=16, parallel_factor=4)
-    b = dynamics.ONNConfig(n=16, backend="hybrid", parallel_factor=4)
-    assert a == b and hash(a) == hash(b)
-
-
 # ---------------------------------------------------------------------------
-# Bit-exactness matrix: MAC widths × execution routes, vs parallel
+# Bit-exactness matrix: MAC widths × modes, vs parallel
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("impl", ["scan", "pallas"])
+@pytest.mark.parametrize("mode", ["functional", "rtl"])
 @pytest.mark.parametrize("n,p", [(12, 1), (12, 5), (12, 12), (20, 7), (9, 2), (33, 32)])
-def test_hybrid_bit_exact_with_parallel(n, p, impl):
+def test_hybrid_bit_exact_with_parallel(n, p, mode):
     """Every tested (N, P): hybrid ≡ parallel on all result fields — P∤N
     ragged tails included, and P=N degenerating to the one-pass parallel
     schedule."""
     w, b, sigma0 = _instance(n * 13 + p, n)
-    cfg_p = dynamics.ONNConfig(n=n, max_cycles=15, settle_chunk=4)
+    cfg_p = dynamics.ONNConfig(n=n, mode=mode, max_cycles=15, settle_chunk=4)
     cfg_h = dynamics.ONNConfig(
-        n=n, backend="hybrid", parallel_factor=p, hybrid_impl=impl,
+        n=n, mode=mode, backend="hybrid", parallel_factor=p,
         max_cycles=15, settle_chunk=4,
     )
     params = dynamics.make_params(cfg_p, w, b)
     ref = dynamics.retrieve(cfg_p, params, sigma0)
     got = dynamics.retrieve(cfg_h, params, sigma0)
-    _assert_results_equal(got, ref, f"n={n} P={p} impl={impl}")
+    _assert_results_equal(got, ref, f"n={n} P={p} mode={mode}")
 
 
 def test_hybrid_mac_sum_matches_parallel_sum():
-    """The scan reference itself, over a sweep of widths."""
+    """The serialized-MAC sum the hybrid backend runs, over a sweep of
+    widths."""
     w, _, sigma0 = _instance(3, 30)
     want = np.asarray(coupling.weighted_sum_parallel(w, sigma0))
     for p in (1, 2, 7, 16, 30):
-        got = np.asarray(dynamics.hybrid_mac_sum(w, sigma0, p))
+        got = np.asarray(coupling.weighted_sum_serial(w, sigma0, chunk=p))
         np.testing.assert_array_equal(got, want, err_msg=f"P={p}")
     with pytest.raises(ValueError):
-        dynamics.hybrid_mac_sum(w, sigma0, 0)
+        coupling.weighted_sum_serial(w, sigma0, chunk=0)
 
 
 def test_hybrid_padded_lanes_bit_exact():

@@ -14,6 +14,7 @@ try:
 except ModuleNotFoundError:  # optional dep (see pyproject.toml): skip, not fail
     from hypothesis_fallback import given, settings, st
 
+from repro.core import coupling
 from repro.kernels import coupling_kernel as kk
 from repro.kernels import ops, ref
 
@@ -107,12 +108,12 @@ def test_property_kernel_exactness(seed, b, n):
 
 
 # ---------------------------------------------------------------------------
-# Hybrid serialized-MAC pass-group kernels
+# The production kernels against the serialized-MAC schedule and the oracles
 # ---------------------------------------------------------------------------
 
-HYBRID_CASES = [
+SERIAL_CASES = [
     # (batch, n, parallel): P=1 single-MAC, ragged P∤N, P=N one pass,
-    # P > pass-group target (one pass per launch), multi-launch shapes.
+    # P wider than a 128 tile, multi-block shapes.
     (3, 9, 1),
     (4, 20, 7),
     (2, 48, 48),
@@ -122,52 +123,42 @@ HYBRID_CASES = [
 ]
 
 
-@pytest.mark.parametrize("b,n,parallel", HYBRID_CASES)
-def test_hybrid_coupling_sum_matches_ref(b, n, parallel):
+@pytest.mark.parametrize("b,n,parallel", SERIAL_CASES)
+def test_coupling_sum_matches_serialized_oracle(b, n, parallel):
+    """The kernel's blocked sum equals the paper's serialized-MAC schedule
+    (``ceil(N / P)`` P-wide passes) and the one-shot oracle."""
     rng = np.random.default_rng(b * 1000 + n + parallel)
     w = jnp.asarray(rng.integers(-15, 16, (n, n)), jnp.int8)
     sig = jnp.asarray(rng.choice([-1, 1], (b, n)), jnp.int8)
-    got = ops.hybrid_coupling_sum(w, sig, parallel=parallel)
-    want = ref.hybrid_coupling_sum_ref(w, sig, parallel)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    # and the serialized schedule is the same integer sum as the parallel one
+    got = np.asarray(ops.coupling_sum(w, sig))
     np.testing.assert_array_equal(
-        np.asarray(got), np.asarray(ref.coupling_sum_ref(w, sig))
+        got, np.asarray(coupling.weighted_sum_serial(w, sig, chunk=parallel))
     )
+    np.testing.assert_array_equal(got, np.asarray(ref.coupling_sum_ref(w, sig)))
 
 
-@pytest.mark.parametrize("b,n,parallel", HYBRID_CASES)
-def test_hybrid_phase_step_matches_ref(b, n, parallel):
-    rng = np.random.default_rng(b * 77 + n + parallel)
+@pytest.mark.parametrize("b,n", [(b, n) for b, n, _ in SERIAL_CASES])
+def test_phase_step_matches_ref(b, n):
+    rng = np.random.default_rng(b * 77 + n)
     w = jnp.asarray(rng.integers(-15, 16, (n, n)), jnp.int8)
     sig = jnp.asarray(rng.choice([-1, 1], (b, n)), jnp.int8)
     bias = jnp.asarray(rng.integers(-10, 11, (n,)), jnp.int32)
     phase = jnp.asarray(rng.integers(0, 16, (b, n)), jnp.uint8)
-    got = ops.hybrid_phase_step(w, sig, bias, phase, half=8, parallel=parallel)
-    want = ref.hybrid_phase_step_ref(w, sig, bias, phase.astype(jnp.int32), 8, parallel)
+    got = ops.phase_step(w, sig, bias, phase, half=8)
+    want = ref.phase_step_ref(w, sig, bias, phase.astype(jnp.int32), 8)
     assert got.dtype == phase.dtype
     np.testing.assert_array_equal(np.asarray(got).astype(np.int32), np.asarray(want))
 
 
-def test_hybrid_phase_step_tie_keeps_phase():
+def test_phase_step_tie_keeps_phase():
     """S == 0 must keep the current (possibly non-canonical) phase counter."""
     n = 24
     w = jnp.zeros((n, n), jnp.int8)
     rng = np.random.default_rng(0)
     sig = jnp.asarray(rng.choice([-1, 1], (4, n)), jnp.int8)
     phase = jnp.asarray(rng.integers(0, 16, (4, n)), jnp.int32)
-    out = ops.hybrid_phase_step(w, sig, None, phase, half=8, parallel=5)
+    out = ops.phase_step(w, sig, None, phase, half=8)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(phase))
-
-
-def test_hybrid_pass_groups_schedule():
-    """Pass-group planning: groups pack whole passes up to the target block."""
-    assert kk.hybrid_pass_groups(1, 128) == (128, 128)
-    assert kk.hybrid_pass_groups(32, 128) == (4, 128)
-    assert kk.hybrid_pass_groups(48, 128) == (2, 96)
-    assert kk.hybrid_pass_groups(200, 128) == (1, 200)  # P > target: 1 pass/launch
-    with pytest.raises(ValueError):
-        kk.hybrid_pass_groups(0)
 
 
 @pytest.mark.parametrize(

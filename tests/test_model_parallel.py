@@ -8,7 +8,7 @@ assertions run here so failures carry readable context.
 
 Covered (ISSUE satellite: CI-runnable multi-device coverage):
   * bit-exactness of the row-sharded ``weighted_sum`` collective vs the
-    replicated path, across all four backends × mesh shapes 1×8 / 2×4 / 4×2,
+    replicated path, across all three backends × mesh shapes 1×8 / 2×4 / 4×2,
     including a non-divisible N (zero-row padding inside the shard_map);
   * retrieve / run end-to-end exactness under an active ShardPlan;
   * the N = 4096 acceptance solve: row-sharded on 8 virtual devices,
@@ -80,7 +80,7 @@ _EXACTNESS_SCRIPT = _PRELUDE + textwrap.dedent(
     """
     rng = np.random.default_rng(0)
     meshes = ((1, 8), (2, 4), (4, 2))
-    backends = ("parallel", "serial", "pallas", "hybrid")
+    backends = ("parallel", "pallas", "hybrid")
 
     # 1) weighted_sum level: every backend x mesh, divisible and non-divisible N
     ws_exact = True

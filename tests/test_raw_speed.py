@@ -203,7 +203,7 @@ def test_phase_step_multi_detects_p2_orbits_and_budget():
 def test_autotune_blocks_deterministic_and_within_budget():
     from repro.kernels import coupling_kernel as ck
 
-    for kind in ("step", "hybrid", "matvec"):
+    for kind in ("step", "matvec"):
         for n in (9, 48, 128, 506, 2048):
             for batch in (1, 16, 256):
                 a = autotune.blocks_for(kind, n=n, batch=batch)
@@ -243,20 +243,21 @@ def test_autotune_step_buckets_fit_double_buffered_budget():
         assert max(sets.values()) <= autotune.STEP_VMEM_BUDGET_BYTES, (shape, cfg)
 
 
-#: "hybrid" tiles as the tuner gave them before the streaming rule: block_b
-#: by batch bucket, block_i == block_k by N bucket.
-_HYBRID_BB = {1: 8, 2: 8, 4: 8, 8: 8, 16: 16, 32: 32, 64: 64, 128: 128, 256: 128}
-_HYBRID_TILE = {16: 16, 32: 32, 48: 48, 64: 64, 128: 128, 256: 256, 506: 256,
-                512: 256, 1024: 256, 2048: 256, 4096: 256}
+#: "step" tiles of the streaming rule (``autotune._step_tiles``) on every
+#: bucket: block_b by batch bucket, block_i == block_k by N bucket.  A change
+#: to the rule must change these on purpose.
+_STEP_BB = {1: 8, 2: 8, 4: 8, 8: 8, 16: 16, 32: 32, 64: 64, 128: 128, 256: 128}
+_STEP_TILE = {16: 16, 32: 32, 48: 48, 64: 64, 128: 128, 256: 256, 506: 512,
+              512: 512, 1024: 1024, 2048: 2048, 4096: 2048}
 
 
-@pytest.mark.parametrize("n", sorted(_HYBRID_TILE))
-def test_autotune_hybrid_tiles_pinned(n):
-    assert set(_HYBRID_TILE) == set(autotune.N_BUCKETS)
-    assert set(_HYBRID_BB) == set(autotune.BATCH_BUCKETS)
-    for batch, bb in _HYBRID_BB.items():
-        got = autotune.blocks_for("hybrid", n=n, batch=batch)
-        assert tuple(got) == (bb, _HYBRID_TILE[n], _HYBRID_TILE[n]), (n, batch)
+@pytest.mark.parametrize("n", sorted(_STEP_TILE))
+def test_autotune_step_tiles_pinned(n):
+    assert set(_STEP_TILE) == set(autotune.N_BUCKETS)
+    assert set(_STEP_BB) == set(autotune.BATCH_BUCKETS)
+    for batch, bb in _STEP_BB.items():
+        got = autotune.blocks_for("step", n=n, batch=batch)
+        assert tuple(got) == (bb, _STEP_TILE[n], _STEP_TILE[n]), (n, batch)
 
 
 def test_cache_info_reports_max_grid_steps():
@@ -264,7 +265,7 @@ def test_cache_info_reports_max_grid_steps():
     try:
         autotune.warm(n=48, batch=16)
         small = autotune.cache_info()["max_grid_steps"]
-        assert set(small) == {"step", "hybrid", "multi"}
+        assert set(small) == {"step", "multi"}
         assert small["step"] == 1 and small["multi"] == 1
         cfg = autotune.blocks_for("step", **SHARDED_BLOCK)
         steps = autotune.cache_info()["max_grid_steps"]["step"]
@@ -280,12 +281,12 @@ def test_autotune_cache_hits_and_warm_idempotent():
     assert info0 == {"entries": 0, "hits": 0, "misses": 0, "max_grid_steps": {}}
     autotune.warm(n=48, batch=16)
     after_first = autotune.cache_info()
-    assert after_first["entries"] == after_first["misses"] == 3
+    assert after_first["entries"] == after_first["misses"] == 2
     autotune.warm(n=48, batch=16)  # idempotent: pure hits
     after_second = autotune.cache_info()
     assert after_second["entries"] == after_first["entries"]
     assert after_second["misses"] == after_first["misses"]
-    assert after_second["hits"] == after_first["hits"] + 3
+    assert after_second["hits"] == after_first["hits"] + 2
     with pytest.raises(ValueError):
         autotune.blocks_for("nope", n=48, batch=16)
     with pytest.raises(ValueError):

@@ -71,15 +71,6 @@ def _phase_step_packed(n, b):
     return fn, [((b, n // 2), jnp.uint8), ((n, n), jnp.int8), ((n,), jnp.int32)]
 
 
-def _hybrid_sum(n, b, parallel):
-    bb, bi, bk = autotune.blocks_for("hybrid", n=n, batch=b)
-    fn = functools.partial(
-        k.hybrid_coupling_sum_pallas, parallel=parallel, block_b=bb, block_i=bi,
-        block_k=bk, interpret=False,
-    )
-    return fn, [((b, n), jnp.int8), ((n, n), jnp.int8)]
-
-
 def _coupling_sum(m, n, b):
     # The row-slab kernel: (M, N) coupling rows against (b, N) spins.
     bb, bi, bk = autotune.blocks_for("step", n=n, batch=b, m=m)
@@ -102,7 +93,6 @@ CASES = {
     "multi_packed_n512_b128": lambda: _multi(512, 128, packed=True),
     "phase_step_n4096_b128": lambda: _phase_step(4096, 128),
     "phase_step_packed_n2048_b128": lambda: _phase_step_packed(2048, 128),
-    "hybrid_coupling_sum_n512_p32": lambda: _hybrid_sum(512, 128, parallel=32),
     "ising_slab_n512_r8": lambda: _ising_slab(512, replicas=8, groups=16),
     # G1 Max-Cut: 16 groups of 64 rows of the 1024 bucket, 32 graphs x 8 replicas.
     "ising_slab_n1024_r256": lambda: _ising_slab(1024, replicas=256, groups=16),
